@@ -21,7 +21,7 @@ import jax
 
 from repro.config import SHAPES, get_config
 from repro.launch.hlo_cost import analyze
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.launch.specs import build_decode, build_prefill, build_train
 from repro.parallel.sharding import act_rules_for, use_mesh
 
@@ -184,7 +184,7 @@ def run_sim_cell(variants):
     from repro.core.response import make_distributed_response
 
     cfg = get_config("lartpc-uboone")  # full MicroBooNE scale, 100k depos
-    mesh = jax.make_mesh((16, 16), ("data", "model"))
+    mesh = make_mesh((16, 16), ("data", "model"))
     nsh = 256
     w_pad, _, _ = padded_grid_shape(cfg, nsh)
     resp = make_distributed_response(cfg, w_pad)

@@ -21,7 +21,9 @@ def main() -> None:
     from benchmarks import (fft, fit, lm_step, pipeline, rasterization,
                             scatter, stages, tune)
     from benchmarks.common import write_json
+    from repro.cache import enable_compile_cache
 
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for mod in [rasterization, scatter, pipeline, stages, fft, tune, lm_step,
                 fit]:

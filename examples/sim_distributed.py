@@ -36,6 +36,7 @@ from repro.core.distributed import (make_distributed_sim,  # noqa: E402
                                     padded_grid_shape, shard_depos)
 from repro.core.response import (make_distributed_plane_responses,  # noqa: E402
                                  make_distributed_response)
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 if args.smoke:
     cfg = LArTPCConfig(num_wires=128, num_ticks=512, num_depos=512,
@@ -49,7 +50,7 @@ if args.planes > 1:
 
 n_dev = len(jax.devices())
 shape = (n_dev // 2, 2) if n_dev % 2 == 0 else (n_dev, 1)
-mesh = jax.make_mesh(shape, ("data", "model"))
+mesh = make_mesh(shape, ("data", "model"))
 print(f"mesh: {dict(mesh.shape)} over {n_dev} devices")
 
 w_pad, _, _ = padded_grid_shape(cfg, n_dev)

@@ -229,16 +229,15 @@ def _build_streaming(ctx: AuditContext):
 
 
 def _dist_setup(ctx: AuditContext, shape: Optional[Tuple[int, int]] = None):
-    import jax
-
     from repro.core.distributed import padded_grid_shape
     from repro.core.response import (make_distributed_plane_responses,
                                      make_distributed_response)
+    from repro.launch.mesh import make_mesh
 
     n_dev = ctx.devices
     if shape is None:  # the examples/sim_distributed.py convention
         shape = (n_dev // 2, 2) if n_dev % 2 == 0 else (n_dev, 1)
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    mesh = make_mesh(shape, ("data", "model"))
     w_pad, _, _ = padded_grid_shape(ctx.cfg, n_dev)
     resp = (make_distributed_plane_responses(ctx.cfg, w_pad)
             if ctx.planes > 1 else make_distributed_response(ctx.cfg, w_pad))
@@ -351,7 +350,7 @@ def extract_contract(jitfn, make_args, *, x64: bool = False) -> Dict:
 
     import jax
 
-    ctx = (jax.experimental.enable_x64() if x64
+    ctx = (jax.enable_x64() if x64
            else contextlib.nullcontext())
     with ctx, warnings.catch_warnings():
         # donated-but-unusable buffers warn per lowering; the *contract*

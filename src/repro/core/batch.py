@@ -29,7 +29,6 @@ every accumulation.
 """
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple, Optional, Sequence
 
 import jax
@@ -38,7 +37,8 @@ import jax.numpy as jnp
 from repro.config import LArTPCConfig
 from repro.core.depo import DepoSet
 from repro.core.response import DetectorResponse
-from repro.core.stages import SimGraph, SimOutput, build_sim_graph
+from repro.core.stages import (SimGraph, SimOutput, build_sim_graph,
+                               jit_executor)
 from repro.parallel.sharding import current_mesh, logical, named_sharding
 
 
@@ -301,15 +301,19 @@ def make_batched_sim_fn(cfg: LArTPCConfig,
     from repro.tune import resolve_config
 
     cfg = resolve_config(cfg)
-    # build_sim_graph supplies the standard RNG pool when cfg asks for it,
-    # and the per-plane default responses when resp is None
-    graph = build_sim_graph(cfg, resp, add_noise=add_noise, recon=recon)
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1) if donate else ())
-    def sim(keys, batch: EventBatch) -> SimOutput:
-        return simulate_events(keys, batch, resp, cfg, graph=graph)
+    def make_fn(r):
+        # build_sim_graph supplies the standard RNG pool when cfg asks for
+        # it; jit_executor the per-plane default responses when resp is None
+        graph = build_sim_graph(cfg, r, add_noise=add_noise, recon=recon)
 
-    return sim
+        def sim(keys, batch: EventBatch) -> SimOutput:
+            return simulate_events(keys, batch, r, cfg, graph=graph)
+
+        return sim
+
+    return jit_executor(cfg, resp, make_fn,
+                        donate_argnums=(0, 1) if donate else ())
 
 
 def shard_events(batch: EventBatch, mesh=None) -> EventBatch:

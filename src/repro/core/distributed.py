@@ -29,7 +29,7 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.config import LArTPCConfig
@@ -406,7 +406,7 @@ def make_distributed_sim(mesh: Mesh, cfg: LArTPCConfig, resp,
         out_specs=(grid_spec if not recon else
                    (grid_spec, grid_spec,
                     P(None, axes) if multi else P(axes))),
-        check_rep=False,
+        check_vma=False,
     )
     if not recon:
         return jax.jit(fn)

@@ -8,10 +8,10 @@ tick, peak sample. The algorithm is sequential in time per wire but
 embarrassingly parallel over wires, which is exactly the portability
 trade-off the registry exists to measure:
 
-  scan   : one ``lax.fori_loop`` run-scanner per wire, ``vmap``-ed over the
-           wire axis — XLA vectorizes the per-tick step across wires.
-  pallas : the same scanner as a Pallas kernel, one grid step per wire
-           (``repro.kernels.hitfind``) — both call the SAME ``_wire_scan``
+  scan   : one ``lax.fori_loop`` over ticks with every wire on its own
+           lane — each per-tick step is a few vector ops over all wires.
+  pallas : the same scanner as a Pallas kernel, 128 wires per grid step
+           (``repro.kernels.hitfind``) — both call the SAME ``scan_runs``
            body, so their outputs are bit-identical by construction.
 
 Output contract (``HitSet``): a fixed-capacity (``cfg.max_hits``), mask-
@@ -50,55 +50,63 @@ class HitSet(NamedTuple):
 
 
 # ---------------------------------------------------------------------------
-# The shared per-wire run scanner (both strategies execute this exact body)
+# The shared run scanner (both strategies execute this exact body)
 # ---------------------------------------------------------------------------
 
 
-def _emit(fire, n, csum, tsum, pk, hq, ht, hp, cap: int):
-    """Close a run: append (charge, mean tick, peak) at slot ``n`` if there
-    is room. ``n`` counts every fired run, stored or not, so truncation at
-    the per-wire capacity is visible to the caller."""
-    ok = fire & (n < cap)
-    idx = jnp.minimum(n, cap - 1)
-    hq = hq.at[idx].set(jnp.where(ok, csum, hq[idx]))
-    ht = ht.at[idx].set(jnp.where(ok, tsum / jnp.maximum(csum, 1e-30),
-                                  ht[idx]))
-    hp = hp.at[idx].set(jnp.where(ok, pk, hp[idx]))
-    return n + fire.astype(jnp.int32), hq, ht, hp
+def scan_runs(load, t_len: int, lanes: int, threshold, cap: int):
+    """Scan ``lanes`` waveforms in lockstep for runs of samples > threshold.
 
-
-def _wire_scan(vals: jax.Array, threshold, cap: int):
-    """Scan one wire's (T,) waveform for runs of samples > threshold.
-
-    Returns (count, charge, tick, peak): count is the TOTAL number of runs
-    found (may exceed ``cap``); the (cap,) arrays hold the first ``cap``
-    runs in time order. Pure jnp + ``fori_loop``, so it runs identically
-    under vmap (the XLA strategy) and inside a Pallas kernel body.
+    ``load(t)`` returns the (1, lanes) float32 samples of tick ``t``: one
+    wire per lane, so every per-tick step is a handful of vector ops over
+    all lanes. Returns (count, charge, tsum, peak): count (1, lanes) int32
+    is the TOTAL number of runs found per wire (may exceed ``cap``);
+    charge/tsum/peak (cap, lanes) float32 hold the first ``cap`` runs in
+    time order, with ``tsum`` the charge-weighted tick sum (``finish_scan``
+    divides it into the mean tick). Runs are closed by masked selects over
+    the ``cap`` slots — no dynamic indexing — so the body lowers unchanged
+    in XLA and in a Mosaic kernel.
     """
-    t_len = vals.shape[0]
+    slot = jax.lax.broadcasted_iota(jnp.int32, (cap, lanes), 0)
+
+    def emit(fire, n, csum, tsum, pk, hq, hts, hp):
+        # a wire that closes its n-th run stores it in slot n, if n < cap;
+        # n counts every run, stored or not, so truncation stays visible
+        put = fire & (slot == n)
+        hq = jnp.where(put, csum, hq)
+        hts = jnp.where(put, tsum, hts)
+        hp = jnp.where(put, pk, hp)
+        return n + fire.astype(jnp.int32), hq, hts, hp
 
     def step(t, carry):
-        n, active, csum, tsum, pk, hq, ht, hp = carry
-        v = vals[t]
+        n, active, csum, tsum, pk, hq, hts, hp = carry
+        v = load(t)
         above = v > threshold
+        run = active != 0
         # a run ends when the previous tick was in-run and this one is not
-        n, hq, ht, hp = _emit(active & ~above, n, csum, tsum, pk,
-                              hq, ht, hp, cap)
+        n, hq, hts, hp = emit(run & ~above, n, csum, tsum, pk, hq, hts, hp)
         tf = t.astype(jnp.float32)
-        csum = jnp.where(above, jnp.where(active, csum + v, v), 0.0)
-        tsum = jnp.where(above, jnp.where(active, tsum + v * tf, v * tf), 0.0)
-        pk = jnp.where(above, jnp.where(active, jnp.maximum(pk, v), v), 0.0)
-        return n, above, csum, tsum, pk, hq, ht, hp
+        csum = jnp.where(above, jnp.where(run, csum + v, v), 0.0)
+        tsum = jnp.where(above, jnp.where(run, tsum + v * tf, v * tf), 0.0)
+        pk = jnp.where(above, jnp.where(run, jnp.maximum(pk, v), v), 0.0)
+        return n, above.astype(jnp.int32), csum, tsum, pk, hq, hts, hp
 
-    zeros = jnp.zeros((cap,), jnp.float32)
-    f0 = jnp.float32(0.0)
-    carry = (jnp.int32(0), jnp.asarray(False), f0, f0, f0,
-             zeros, zeros, zeros)
-    n, active, csum, tsum, pk, hq, ht, hp = jax.lax.fori_loop(
+    row = jnp.zeros((1, lanes), jnp.float32)
+    cand = jnp.zeros((cap, lanes), jnp.float32)
+    carry = (jnp.zeros((1, lanes), jnp.int32), jnp.zeros((1, lanes), jnp.int32),
+             row, row, row, cand, cand, cand)
+    n, active, csum, tsum, pk, hq, hts, hp = jax.lax.fori_loop(
         0, t_len, step, carry)
     # flush a run still open at the readout edge
-    n, hq, ht, hp = _emit(active, n, csum, tsum, pk, hq, ht, hp, cap)
-    return n, hq, ht, hp
+    return emit(active != 0, n, csum, tsum, pk, hq, hts, hp)
+
+
+def finish_scan(n, hq, hts, hp):
+    """Lane-major scan outputs -> the per-wire candidate layout: counts
+    (W,) and charge/tick/peak (W, cap), tick = tsum / charge (empty slots
+    stay 0)."""
+    tick = hts / jnp.maximum(hq, 1e-30)
+    return n[0], hq.T, tick.T, hp.T
 
 
 # ---------------------------------------------------------------------------
@@ -111,12 +119,14 @@ def _wire_scan(vals: jax.Array, threshold, cap: int):
 
 
 @register_strategy("hit_find", "scan",
-                   note="per-wire fori_loop run scanner, vmap over wires",
+                   note="fori_loop run scanner over ticks, wires on lanes",
                    differentiable=False)
 def hit_find_scan(decon: jax.Array, cfg: LArTPCConfig):
-    thr = jnp.float32(cfg.hit_threshold)
-    cap = int(cfg.max_hits_per_wire)
-    return jax.vmap(lambda row: _wire_scan(row, thr, cap))(decon)
+    w, t_len = decon.shape
+    q = decon.astype(jnp.float32).T                      # (T, W)
+    return finish_scan(*scan_runs(
+        lambda t: jax.lax.dynamic_slice_in_dim(q, t, 1, axis=0), t_len, w,
+        jnp.float32(cfg.hit_threshold), int(cfg.max_hits_per_wire)))
 
 
 def _pallas_viable(ctx) -> bool:
@@ -129,7 +139,7 @@ def _pallas_viable(ctx) -> bool:
 
 
 @register_strategy("hit_find", "pallas", available=_pallas_viable,
-                   note="one Pallas grid step per wire (same scan body)",
+                   note="Pallas kernel, 128 wires per grid step (same body)",
                    differentiable=False)
 def hit_find_pallas(decon: jax.Array, cfg: LArTPCConfig):
     from repro.kernels.hitfind.ops import find_wire_hits_pallas

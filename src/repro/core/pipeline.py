@@ -36,7 +36,7 @@ from repro.core.rasterize import rasterize, rasterize_one
 from repro.core.response import DetectorResponse, make_response
 from repro.core.scatter import scatter_add
 from repro.core.stages import (SimOutput, build_sim_graph,
-                               compute_charge_grid)
+                               compute_charge_grid, jit_executor)
 from repro.tune.registry import register_strategy, set_default
 
 __all__ = [
@@ -81,6 +81,16 @@ def charge_grid_unfused_bf16(key: jax.Array, depos: DepoSet,
         key, depos, dataclasses.replace(cfg, patch_dtype="bfloat16"), pool)
 
 
+#: Mosaic (JAX 0.9.0) refuses every fused kernel at lowering:
+#: "Unimplemented primitive in Pallas TPU lowering for KernelType.TC: erf".
+#: Past that, the kernels put each per-tile depo-id list and the seven (N,)
+#: depo-parameter arrays in scalar prefetch (SMEM): ~28 MB at the full
+#: config against an SMEM of well under 1 MB. Until the kernels are
+#: rewritten they are never selectable on a TPU.
+FUSED_TPU_REFUSAL = ("Unimplemented primitive in Pallas TPU lowering for "
+                     "KernelType.TC: erf")
+
+
 def _fused_viable(ctx) -> bool:
     # the fused kernel draws counter-style fluctuation randomness in kernel,
     # so it competes in the physics-default config; the paper-faithful
@@ -90,7 +100,7 @@ def _fused_viable(ctx) -> bool:
     if cfg is None or (cfg.fluctuate and cfg.rng_strategy in ("pool", "relaxed")):
         return False
     if ctx.backend == "tpu":
-        return True
+        return False  # FUSED_TPU_REFUSAL
     cells = ctx.shape.get("num_wires", 0) * ctx.shape.get("num_ticks", 0)
     return cells <= (1 << 21)
 
@@ -143,7 +153,7 @@ def _fused_mp_viable(ctx) -> bool:
     if cfg.fluctuate and cfg.rng_strategy in ("pool", "relaxed"):
         return False
     if ctx.backend == "tpu":
-        return True
+        return False  # FUSED_TPU_REFUSAL
     cells = (ctx.shape.get("num_wires", 0) * ctx.shape.get("num_ticks", 0)
              * cfg.num_planes)
     return cells <= (1 << 21)
@@ -365,10 +375,12 @@ def make_sim_fn(cfg: LArTPCConfig, resp: Optional[DetectorResponse] = None,
     from repro.tune import resolve_config
 
     cfg = resolve_config(cfg)
-    # build_sim_graph supplies the standard RNG pool when cfg asks for it,
-    # and the per-plane default responses when resp is None
-    graph = build_sim_graph(cfg, resp, add_noise=add_noise, recon=recon)
-    return jax.jit(graph.run, donate_argnums=(0, 1) if donate else ())
+    # build_sim_graph supplies the standard RNG pool when cfg asks for it;
+    # jit_executor the per-plane default responses when resp is None
+    return jit_executor(
+        cfg, resp, lambda r: build_sim_graph(cfg, r, add_noise=add_noise,
+                                             recon=recon).run,
+        donate_argnums=(0, 1) if donate else ())
 
 
 def simulate(key: jax.Array, depos: DepoSet, cfg: LArTPCConfig,

@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import LArTPCConfig
-from repro.kernels import default_interpret
 from repro.tune.registry import register_strategy, set_default
 
 
@@ -116,19 +115,29 @@ def scatter_sort_segment(patches: jax.Array, w0: jax.Array, t0: jax.Array,
     return grid.reshape(cfg.num_wires, cfg.num_ticks)
 
 
+#: Mosaic (JAX 0.9.0) refuses both owner-computes kernels at lowering:
+#: "Unimplemented primitive in Pallas TPU lowering for KernelType.TC:
+#: dynamic_update_slice" (the patch placement). Past that, the per-tile
+#: depo-id list rides in scalar prefetch (SMEM): 25 MB at the full config.
+#: Until the kernels are rewritten they are never selectable on a TPU.
+SCATTER_TPU_REFUSAL = ("Unimplemented primitive in Pallas TPU lowering for "
+                       "KernelType.TC: dynamic_update_slice")
+
+
 def _pallas_viable(ctx) -> bool:
-    # Compiled on TPU; anywhere else the kernel runs in the Pallas
-    # interpreter, which is a correctness tool — keep it out of the tuner's
-    # candidate set once the grid is big enough that interpret-mode tile
-    # loops dominate (it would never win, only slow tuning down).
+    # Refused by the TPU compiler (SCATTER_TPU_REFUSAL). Anywhere else the
+    # kernel runs in the Pallas interpreter, which is a correctness tool —
+    # keep it out of the tuner's candidate set once the grid is big enough
+    # that interpret-mode tile loops dominate (it would never win, only
+    # slow tuning down).
     if ctx.backend == "tpu":
-        return True
+        return False
     cells = ctx.shape.get("num_wires", 0) * ctx.shape.get("num_ticks", 0)
     return cells <= (1 << 21)
 
 
 @register_strategy("scatter_add", "pallas", available=_pallas_viable,
-                   note="owner-computes tile kernel; interpret off-TPU",
+                   note="owner-computes tile kernel; interpret only",
                    differentiable=False)
 def scatter_pallas(patches: jax.Array, w0: jax.Array, t0: jax.Array,
                    cfg: LArTPCConfig, interpret: bool | None = None):
@@ -137,7 +146,7 @@ def scatter_pallas(patches: jax.Array, w0: jax.Array, t0: jax.Array,
     return scatter_add_tiles(
         patches, w0, t0,
         num_wires=cfg.num_wires, num_ticks=cfg.num_ticks,
-        interpret=default_interpret() if interpret is None else interpret,
+        interpret=interpret,
     )
 
 
@@ -151,7 +160,7 @@ def scatter_pallas_compact(patches: jax.Array, w0: jax.Array, t0: jax.Array,
     return scatter_add_tiles_compact(
         patches, w0, t0,
         num_wires=cfg.num_wires, num_ticks=cfg.num_ticks,
-        interpret=default_interpret() if interpret is None else interpret,
+        interpret=interpret,
     )
 
 

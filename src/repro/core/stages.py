@@ -18,6 +18,8 @@ All four production entry points execute the same graph object:
 
   make_sim_fn           : jit(graph.run)                       (single event)
   make_batched_sim_fn   : jit(vmap(graph.run))                 (event batch)
+                          (both via ``jit_executor``: the response spectra
+                          are program arguments, not baked-in literals)
   make_distributed_sim  : jit(shard_map(graph.run))            (multi-device,
                           with charge_grid/convolve/noise stage overrides)
   stream_simulate       : the double-buffered driver over make_batched_sim_fn
@@ -635,3 +637,48 @@ def build_sim_graph(cfg: LArTPCConfig, resp=None,
     if overrides:
         graph = graph.replace(**overrides)
     return graph
+
+
+class _Bound:
+    """A jitted ``fn(consts, *args)`` (or its ``Lowered``/``Compiled``
+    stage) called as ``f(*args)``: ``consts`` stay bound through
+    ``lower``/``compile``; every other attribute is the wrapped object's."""
+
+    def __init__(self, inner, consts):
+        self._inner, self._consts = inner, consts
+
+    def __call__(self, *args):
+        return self._inner(self._consts, *args)
+
+    def lower(self, *args):
+        return _Bound(self._inner.lower(self._consts, *args), self._consts)
+
+    def compile(self):
+        return _Bound(self._inner.compile(), self._consts)
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+
+def jit_executor(cfg: LArTPCConfig, resp, make_fn: Callable,
+                 donate_argnums: Tuple[int, ...] = ()):
+    """``jax.jit`` an executor whose detector responses are program
+    ARGUMENTS, not literals baked into the compiled program.
+
+    A jitted function embeds every array it closes over. The response
+    spectra are ~100 MB per plane at the full config (more with recon's
+    inverse filters, which derive from them), so an executor closing over
+    its graph compiles slowly into a program of hundreds of MB that a
+    size-capped persistent compilation cache refuses. Here
+    ``make_fn(resp)`` builds the executor from the responses inside the
+    trace; the result is called (and ``lower``-ed) with the executor's own
+    arguments, ``donate_argnums`` counting those.
+    """
+    resps = _as_plane_responses(cfg, resp, None)
+
+    def run(freqs, *args):
+        live = tuple(r._replace(freq=f) for r, f in zip(resps, freqs))
+        return make_fn(live if cfg.num_planes > 1 else live[0])(*args)
+
+    jitted = jax.jit(run, donate_argnums=tuple(i + 1 for i in donate_argnums))
+    return _Bound(jitted, tuple(r.freq for r in resps))

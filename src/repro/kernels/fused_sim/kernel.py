@@ -37,6 +37,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.fluctuate import box_muller, counter_normals, uniform_from_bits
+from repro.kernels import default_interpret
 
 _SQRT2 = 1.4142135623730951
 #: stream-id mixing constants (distinct odd 32-bit constants so the
@@ -262,7 +263,7 @@ def _seed_operand_planes(seeds, num_planes: int):
 def fused_rasterize_scatter(wire, tick, sigma_w, sigma_t, charge, w0, t0,
                             tile_ids, *, num_wires: int, num_ticks: int,
                             tw: int, tt: int, k_max: int, pw: int, pt: int,
-                            interpret: bool = True, seed=None,
+                            interpret: bool | None = None, seed=None,
                             fluctuate: bool = False):
     """Depos -> charge grid in ONE kernel (no patch array in HBM).
 
@@ -270,6 +271,7 @@ def fused_rasterize_scatter(wire, tick, sigma_w, sigma_t, charge, w0, t0,
     depo params (N,) f32 / int32, seed (2,) int32 raw key data (only read
     when ``fluctuate``).
     """
+    interpret = default_interpret() if interpret is None else interpret
     tiles_w = (num_wires + tw - 1) // tw
     tiles_t = (num_ticks + tt - 1) // tt
     n_tiles = tiles_w * tiles_t
@@ -301,7 +303,7 @@ def fused_rasterize_scatter_compact(wire, tick, sigma_w, sigma_t, charge,
                                     w0, t0, active_tiles, tile_ids, *,
                                     num_wires: int, num_ticks: int, tw: int,
                                     tt: int, k_max: int, pw: int, pt: int,
-                                    interpret: bool = True, seed=None,
+                                    interpret: bool | None = None, seed=None,
                                     fluctuate: bool = False):
     """Active-tile fused kernel: grid (n_active, k_max), not (n_tiles, k_max).
 
@@ -310,6 +312,7 @@ def fused_rasterize_scatter_compact(wire, tick, sigma_w, sigma_t, charge,
     The kernel emits one (tw, tt) block per active slot; the blocks are then
     scattered back into the full grid (an O(occupied area) write).
     """
+    interpret = default_interpret() if interpret is None else interpret
     tiles_w = (num_wires + tw - 1) // tw
     tiles_t = (num_ticks + tt - 1) // tt
     n_tiles = tiles_w * tiles_t
@@ -341,7 +344,7 @@ def fused_rasterize_scatter_multiplane(wire, tick, sigma_w, sigma_t, charge,
                                        w0, t0, tile_ids, *, num_planes: int,
                                        num_wires: int, num_ticks: int,
                                        tw: int, tt: int, k_max: int, pw: int,
-                                       pt: int, interpret: bool = True,
+                                       pt: int, interpret: bool | None = None,
                                        seeds=None, fluctuate: bool = False):
     """All P planes' charge grids in ONE kernel launch (dense tile layout).
 
@@ -353,6 +356,7 @@ def fused_rasterize_scatter_multiplane(wire, tick, sigma_w, sigma_t, charge,
     plane p bit-identical to ``fused_rasterize_scatter`` with plane p's
     params and seed.
     """
+    interpret = default_interpret() if interpret is None else interpret
     n = wire.shape[-1]
     tiles_w = (num_wires + tw - 1) // tw
     tiles_t = (num_ticks + tt - 1) // tt
@@ -392,7 +396,8 @@ def fused_rasterize_scatter_multiplane(wire, tick, sigma_w, sigma_t, charge,
 def fused_rasterize_scatter_multiplane_compact(
         wire, tick, sigma_w, sigma_t, charge, w0, t0, active_tiles, tile_ids,
         *, num_planes: int, num_wires: int, num_ticks: int, tw: int, tt: int,
-        k_max: int, pw: int, pt: int, interpret: bool = True, seeds=None,
+        k_max: int, pw: int, pt: int, interpret: bool | None = None,
+        seeds=None,
         fluctuate: bool = False):
     """Active-tile multi-plane fused kernel: grid (P*n_cap, k_max).
 
@@ -403,6 +408,7 @@ def fused_rasterize_scatter_multiplane_compact(
     dense multi-plane kernel (RNG streams key on plane-local tile ids,
     which compaction preserves).
     """
+    interpret = default_interpret() if interpret is None else interpret
     n = wire.shape[-1]
     tiles_w = (num_wires + tw - 1) // tw
     tiles_t = (num_ticks + tt - 1) // tt

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 
 from repro.config import LArTPCConfig
 from repro.core.depo import DepoSet, depo_patch_origin
-from repro.kernels import default_interpret
 from repro.kernels.fused_sim.kernel import (
     fused_rasterize_scatter, fused_rasterize_scatter_compact,
     fused_rasterize_scatter_multiplane,
@@ -63,7 +62,6 @@ def simulate_charge_grid(depos: DepoSet, cfg: LArTPCConfig, tw: int = 64,
     ``interpret=None`` auto-selects by backend: Mosaic-compiled on TPU, the
     portable Pallas interpreter elsewhere (``repro.kernels.default_interpret``).
     """
-    interpret = default_interpret() if interpret is None else interpret
     w0, t0 = depo_patch_origin(depos, cfg)
     k_max = _resolve_k_max(k_max, depos.n, cfg, tw, tt)
     # bin by the TRUE patch extent (the kernel masks to [w0, w0+pw))
@@ -103,7 +101,6 @@ def simulate_charge_grid_compact(depos: DepoSet, cfg: LArTPCConfig,
     Bit-identical to ``simulate_charge_grid`` for the same key: RNG streams
     are seeded by the *global* tile id, which compaction preserves.
     """
-    interpret = default_interpret() if interpret is None else interpret
     _, _, n_tiles = _grid_dims(cfg, tw, tt)
     k_max = _resolve_k_max(k_max, depos.n, cfg, tw, tt)
     if n_active is not None:
@@ -131,7 +128,6 @@ def simulate_charge_grid_multiplane(depos: DepoSet, cfg: LArTPCConfig,
     in-kernel fluctuation; plane p's grid is bit-identical to
     ``simulate_charge_grid`` run on plane p's depos with plane p's key.
     """
-    interpret = default_interpret() if interpret is None else interpret
     num_planes, n = depos.wire.shape
     w0, t0 = depo_patch_origin(depos, cfg)
     k_max = _resolve_k_max(k_max, n, cfg, tw, tt)
@@ -186,7 +182,6 @@ def simulate_charge_grid_multiplane_compact(depos: DepoSet,
     launch stays rectangular. Bit-identical to
     ``simulate_charge_grid_multiplane`` for the same keys.
     """
-    interpret = default_interpret() if interpret is None else interpret
     _, _, n_tiles = _grid_dims(cfg, tw, tt)
     num_planes = depos.wire.shape[0]
     k_max = _resolve_k_max(k_max, depos.n, cfg, tw, tt)

@@ -1,14 +1,13 @@
-"""Pallas kernel: per-wire threshold-run hit scanner.
+"""Pallas kernel: threshold-run hit scanner, 128 wires per grid step.
 
-One grid step per wire: the step DMAs that wire's (1, T) waveform block into
-VMEM, runs the SAME ``_wire_scan`` body the XLA strategy vmaps (a
-``fori_loop`` over ticks — sequential in time, parallel over wires, the
-natural decomposition the hit-finding paper (arXiv:2107.00812) settles on),
-and writes the wire's (1, cap) candidate rows plus its (1, 1) run count.
-
-The candidate arrays ride the loop carry in registers/VMEM and store once at
-the end — no scatter into the output ref from inside the loop. The threshold
-and per-wire capacity are baked in as Python statics (they come from the
+The deconvolved grid arrives time-major, (T, W): grid step j DMAs the
+(T, 128) block of wires [128j, 128j + 128) into VMEM and runs the SAME
+``scan_runs`` body the XLA strategy runs (a ``fori_loop`` over ticks,
+sequential in time and parallel over wires, the decomposition the
+hit-finding paper (arXiv:2107.00812) settles on), with one wire per vector
+lane. Each tick reads one (1, 128) row of the block; the candidate slots
+are (cap, 128) arrays carried through the loop and stored once at the end.
+The threshold and per-wire capacity are Python statics (they come from the
 config, which is static under jit anyway).
 """
 from __future__ import annotations
@@ -19,49 +18,48 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.hitfind import _wire_scan
+from repro.core.hitfind import scan_runs
+from repro.kernels import default_interpret
+
+#: wires per grid step: one per vector lane
+LANES = 128
 
 
-def _hitfind_kernel(q_ref, counts_ref, hq_ref, ht_ref, hp_ref, *,
+def _hitfind_kernel(q_ref, counts_ref, hq_ref, hts_ref, hp_ref, *,
                     threshold: float, cap: int):
-    """Grid step w: scan wire w's waveform block for above-threshold runs.
+    """Grid step j: scan the (T, LANES) block of wires for runs.
 
-    q_ref: (1, T) VMEM block of the deconvolved grid's wire w.
-    counts_ref: (1, 1) int32; hq/ht/hp_ref: (1, cap) float32 outputs.
+    counts_ref: (1, LANES) int32; hq/hts/hp_ref: (cap, LANES) float32.
     """
-    vals = q_ref[0, :].astype(jnp.float32)
-    n, hq, ht, hp = _wire_scan(vals, jnp.float32(threshold), cap)
-    counts_ref[0, 0] = n
-    hq_ref[0, :] = hq
-    ht_ref[0, :] = ht
-    hp_ref[0, :] = hp
+    t_len = q_ref.shape[0]
+    n, hq, hts, hp = scan_runs(
+        lambda t: q_ref[pl.ds(t, 1), :].astype(jnp.float32), t_len, LANES,
+        jnp.float32(threshold), cap)
+    counts_ref[...] = n
+    hq_ref[...] = hq
+    hts_ref[...] = hts
+    hp_ref[...] = hp
 
 
-def hitfind_pallas(decon: jax.Array, *, threshold: float, cap: int,
-                   interpret: bool = True):
-    """Run the per-wire scanner over a (W, T) deconvolved grid.
+def hitfind_pallas(q: jax.Array, *, threshold: float, cap: int,
+                   interpret: bool | None = None):
+    """Run the scanner over a time-major (T, W) grid, W a multiple of 128.
 
-    Returns (counts (W, 1) int32, charge (W, cap), tick (W, cap),
-    peak (W, cap)) — the per-wire candidate layout ``compact_hits`` takes
-    (the caller squeezes counts).
+    Returns (counts (1, W) int32, charge (cap, W), tsum (cap, W),
+    peak (cap, W)) — ``scan_runs``'s lane-major layout.
     """
-    w, t_len = decon.shape
+    interpret = default_interpret() if interpret is None else interpret
+    t_len, w = q.shape
+    assert w % LANES == 0, f"pad the wire axis ({w}) to a multiple of {LANES}"
     kernel = functools.partial(_hitfind_kernel, threshold=threshold, cap=cap)
+    cand = jax.ShapeDtypeStruct((cap, w), jnp.float32)
+    cand_spec = pl.BlockSpec((cap, LANES), lambda j: (0, j))
     return pl.pallas_call(
         kernel,
-        grid=(w,),
-        in_specs=[pl.BlockSpec((1, t_len), lambda i: (i, 0))],
-        out_specs=(
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, cap), lambda i: (i, 0)),
-            pl.BlockSpec((1, cap), lambda i: (i, 0)),
-            pl.BlockSpec((1, cap), lambda i: (i, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((w, 1), jnp.int32),
-            jax.ShapeDtypeStruct((w, cap), jnp.float32),
-            jax.ShapeDtypeStruct((w, cap), jnp.float32),
-            jax.ShapeDtypeStruct((w, cap), jnp.float32),
-        ),
+        grid=(w // LANES,),
+        in_specs=[pl.BlockSpec((t_len, LANES), lambda j: (0, j))],
+        out_specs=(pl.BlockSpec((1, LANES), lambda j: (0, j)),
+                   cand_spec, cand_spec, cand_spec),
+        out_shape=(jax.ShapeDtypeStruct((1, w), jnp.int32), cand, cand, cand),
         interpret=interpret,
-    )(decon)
+    )(q)

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import default_interpret
+
 _SQRT2 = 1.4142135623730951
 
 
@@ -78,12 +80,13 @@ def _rasterize_kernel(wire_ref, tick_ref, sw_ref, st_ref, q_ref,
 def rasterize_pallas(wire, tick, sigma_w, sigma_t, charge, w0, t0, u1, u2, *,
                      pw: int, pt: int, pw_pad: int = 0, pt_pad: int = 128,
                      depo_block: int = 256, fluctuate: bool = True,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """Rasterize all depos in one pallas_call.
 
     Args: depo params (N,) f32 (w0/t0 pre-cast to f32); u1/u2 (N, PW, PT)
     uniform pools. Returns (N, PW_pad, PT_pad) f32 patches (padding zeroed).
     """
+    interpret = default_interpret() if interpret is None else interpret
     n = wire.shape[0]
     pw_pad = pw_pad or ((pw + 7) // 8 * 8)
     assert pt <= pt_pad and pw <= pw_pad

@@ -32,10 +32,12 @@ def _pad_depos(depos: DepoSet, block: int):
                                              "interpret"))
 def rasterize_depos(key: jax.Array, depos: DepoSet, cfg: LArTPCConfig,
                     depo_block: int = 256, fluctuate: bool = True,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """Rasterize (+fluctuate) every depo with the Pallas kernel.
 
     Returns (patches (N, PW_pad, PT_pad), w0, t0) — N is the original count.
+    ``interpret=None`` compiles on TPU and interprets elsewhere
+    (``repro.kernels.default_interpret``).
     """
     padded, n = _pad_depos(depos, depo_block)
     w0, t0 = depo_patch_origin(padded, cfg)

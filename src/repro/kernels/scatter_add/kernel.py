@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import default_interpret
+
 
 def _scatter_kernel(ids_ref, w0_ref, t0_ref, patch_ref, out_ref, *,
                     k_max: int, tw: int, tt: int, pw_pad: int, pt_pad: int,
@@ -96,7 +98,7 @@ def _scatter_kernel_compact(tiles_ref, ids_ref, w0_ref, t0_ref, patch_ref,
 
 def scatter_add_pallas(patches, w0, t0, tile_ids, *, num_wires: int,
                        num_ticks: int, tw: int, tt: int, k_max: int,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """Owner-computes scatter-add.
 
     patches  : (N, PW_pad, PT_pad) f32 (zero-padded beyond the true patch)
@@ -104,6 +106,7 @@ def scatter_add_pallas(patches, w0, t0, tile_ids, *, num_wires: int,
     tile_ids : (n_tiles * k_max,) int32 depo ids per tile, -1 padded
     Returns the (num_wires_padded, num_ticks_padded) grid (tile-aligned).
     """
+    interpret = default_interpret() if interpret is None else interpret
     n, pw_pad, pt_pad = patches.shape
     tiles_w = (num_wires + tw - 1) // tw
     tiles_t = (num_ticks + tt - 1) // tt
@@ -139,7 +142,7 @@ def scatter_add_pallas(patches, w0, t0, tile_ids, *, num_wires: int,
 
 def scatter_add_pallas_compact(patches, w0, t0, active_tiles, tile_ids, *,
                                num_wires: int, num_ticks: int, tw: int,
-                               tt: int, k_max: int, interpret: bool = True):
+                               tt: int, k_max: int, interpret: bool | None = None):
     """Active-tile owner-computes scatter-add.
 
     active_tiles : (n_active,) int32 global tile ids of occupied tiles, -1
@@ -148,6 +151,7 @@ def scatter_add_pallas_compact(patches, w0, t0, active_tiles, tile_ids, *,
     Returns (n_active, tw, tt) f32 tile blocks — the caller scatters them
     back into the full grid (see ``fused_sim.kernel.scatter_tiles_to_grid``).
     """
+    interpret = default_interpret() if interpret is None else interpret
     n, pw_pad, pt_pad = patches.shape
     tiles_t = (num_ticks + tt - 1) // tt
     n_active = active_tiles.shape[0]

@@ -195,9 +195,6 @@ def scatter_add_tiles(patches, w0, t0, *, num_wires: int, num_ticks: int,
     ``interpret=None`` auto-selects by backend (compiled on TPU, interpreter
     elsewhere). Returns (num_wires, num_ticks) f32 grid.
     """
-    from repro.kernels import default_interpret
-
-    interpret = default_interpret() if interpret is None else interpret
     n, pw_pad, pt_pad = patches.shape
     tw = max(tw, pw_pad)
     tt = max(tt, pt_pad)
@@ -243,9 +240,6 @@ def scatter_add_tiles_compact(patches, w0, t0, *, num_wires: int,
     proportional to occupied readout area. ``n_active`` overrides the
     occupancy measurement (it is bucketed, and must be >= the true count).
     """
-    from repro.kernels import default_interpret
-
-    interpret = default_interpret() if interpret is None else interpret
     n, pw_pad, pt_pad = patches.shape
     tw = max(tw, pw_pad)
     tt = max(tt, pt_pad)

@@ -32,6 +32,7 @@ import time
 # NOTE: no jax / repro.core imports at module scope — --grad-smoke must set
 # XLA_FLAGS (forced host device count) before jax first loads. Everything
 # jax-touching imports inside the run_* functions.
+from repro.cache import enable_compile_cache
 from repro.config import apply_overrides, get_config
 
 #: the --smoke scenario: seeded truth, deliberately-wrong starting points
@@ -145,7 +146,9 @@ def run_grad_smoke(args) -> int:
     depos = targets.batch.physical_set()
     g_single = grad_fn(theta0, targets.keys, depos, target_adc)
 
-    mesh = jax.make_mesh((n_dev,), ("events",))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((n_dev,), ("events",))
     ev_sharded = NamedSharding(mesh, P("events"))
 
     def shard(x):
@@ -236,6 +239,8 @@ def main(argv=None):
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices} "
             + os.environ.get("XLA_FLAGS", ""))
+    enable_compile_cache()
+    if args.grad_smoke:
         return run_grad_smoke(args)
     if args.gradcheck:
         return run_gradcheck(args)

@@ -33,6 +33,7 @@ from typing import Callable, Optional
 import jax
 import numpy as np
 
+from repro.cache import enable_compile_cache
 from repro.config import LArTPCConfig, apply_overrides, get_config
 from repro.core import generate_depos, simulate
 from repro.core.batch import (empty_event, event_keys, make_batched_sim_fn,
@@ -366,6 +367,7 @@ def main():
 
     if args.resume and not args.journal:
         raise SystemExit("--resume needs --journal PATH")
+    enable_compile_cache()
 
     cfg = get_config("lartpc-uboone", smoke=args.smoke)
     if args.depos:

@@ -7,11 +7,10 @@ from __future__ import annotations
 
 import argparse
 
-import jax
-
 from repro.config import (CheckpointConfig, OptimizerConfig, ShapeConfig,
                           SHAPES, TrainConfig, apply_overrides, get_config,
                           list_archs)
+from repro.launch.mesh import make_mesh
 from repro.parallel.sharding import act_rules_for, use_mesh
 from repro.train.trainer import Trainer
 
@@ -49,7 +48,7 @@ def main():
     if args.mesh:
         dims = tuple(int(x) for x in args.mesh.split("x"))
         axes = ("data", "model")[:len(dims)]
-        mesh = jax.make_mesh(dims, axes)
+        mesh = make_mesh(dims, axes)
 
     with use_mesh(mesh, act_rules_for(model_cfg, mesh)):
         result = Trainer(cfg, mesh=mesh).run(max_steps=args.steps)

@@ -16,7 +16,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -74,5 +74,5 @@ def pipeline_apply(stage_fn: Callable, stage_params: Any, x: jax.Array,
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
     fn = shard_map(local, mesh=mesh,
                    in_specs=(pspec, P()), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     return fn(stage_params, x)

@@ -17,7 +17,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.config import OptimizerConfig
@@ -69,7 +69,7 @@ def make_compressed_train_step(model: Model, opt_cfg: OptimizerConfig,
             {"loss": P(), "aux": P(), "lr": P(), "grad_norm": P()},
         )
         fn = shard_map(local_step, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+                       out_specs=out_specs, check_vma=False)
         return fn(params, state, batch)
 
     return step

@@ -9,7 +9,7 @@ at the *actual* problem shape, then cached so later runs skip re-tuning:
 
 Shape dims are bucketed to the next power of two, so e.g. 100_000 and
 120_000 depos share one decision but 1_000 does not. The cache is a single
-JSON file (default ``~/.cache/repro-tune/tune_cache.json``, override with
+JSON file (default ``<repo>/.repro_tune/tune_cache.json``, override with
 ``$REPRO_TUNE_CACHE``) — human-readable, diffable, safe to delete.
 
 Resolution order for a strategy-valued config field:
@@ -67,11 +67,9 @@ PLANE_KEYED_OPS = ("fft_convolve", "deconvolve")
 
 
 def default_cache_path() -> str:
-    env = os.environ.get(CACHE_ENV)
-    if env:
-        return env
-    home = os.path.expanduser("~")
-    return os.path.join(home, ".cache", "repro-tune", "tune_cache.json")
+    from repro.cache import TUNE_CACHE
+
+    return os.environ.get(CACHE_ENV) or str(TUNE_CACHE)
 
 
 class TuneCache:

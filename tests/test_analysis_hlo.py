@@ -149,7 +149,7 @@ class TestLiveJax:
             return (x.astype(jnp.float64) * jnp.float64(1.0 + 1e-12)  # repro-lint: disable=f64-literal
                     ).astype(jnp.float32)
 
-        with jax.experimental.enable_x64():
+        with jax.enable_x64():
             txt = jax.jit(f).lower(
                 jnp.ones(8, jnp.float32)).compile().as_text()
         assert "f64" in hlo.dtype_census(txt)

@@ -167,7 +167,7 @@ class TestFixturePrograms:
     def test_extra_allreduce_fixture_flagged(self):
         """A deliberate collective in a 'local' program — built with a
         1-device psum under shard_map — must trip the local policy."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         mesh = Mesh(jax.devices("cpu")[:1], ("d",))

@@ -29,7 +29,8 @@ opt_cfg = OptimizerConfig(lr=1e-3, warmup_steps=0, total_steps=50,
                           schedule="constant")
 model = Model(cfg)
 params0 = model.init(jax.random.key(0))
-mesh = jax.make_mesh((2,), ("pod",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2,), ("pod",))
 
 # exact (uncompressed) reference on one device
 p_ref = params0

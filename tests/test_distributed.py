@@ -27,7 +27,8 @@ results = {}
 # ---- distributed LArTPC sim matches single-device cyclic reference ----
 cfg = LArTPCConfig(num_wires=128, num_ticks=512, num_depos=256,
                    response_wires=11, response_ticks=64, fluctuate=False)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 w_pad, _, _ = padded_grid_shape(cfg, 8)
 resp = make_distributed_response(cfg, w_pad)
 key = jax.random.key(0)

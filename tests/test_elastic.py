@@ -43,7 +43,8 @@ from repro.parallel.sharding import use_mesh, act_rules_for
 cfg = ModelConfig(num_layers=2, d_model=32, num_heads=4, num_kv_heads=2,
                   d_ff=64, vocab_size=256, remat="none", dtype="float32")
 model = Model(cfg)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 params_t = model.init(jax.random.key(0))
 opt_t = init_opt_state(params_t)
 param_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), model.specs(mesh))

@@ -18,6 +18,7 @@ from repro.core.batch import (EventBatch, empty_event, event_keys,
 from repro.core.depo import DepoSet, generate_depos
 from repro.core.pipeline import simulate_fig4
 from repro.core.response import make_response
+from repro.launch.mesh import make_mesh
 from repro.launch.sim import stream_simulate
 
 CFG = LArTPCConfig(num_wires=64, num_ticks=256, num_depos=48,
@@ -194,7 +195,7 @@ class TestSharding:
     def test_event_axis_rule_registered(self):
         from repro.parallel.sharding import ACT_RULES, build_spec
         assert "events" in ACT_RULES
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         spec = build_spec((4, 8), ("events", None), mesh, ACT_RULES)
         assert spec[0] == "data"
 
@@ -206,7 +207,7 @@ class TestSharding:
         keys = event_keys(jax.random.key(0), range(2))
         resp = make_response(CFG)
         ref = simulate_events(keys, batch, resp, CFG)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with use_mesh(mesh):
             sim = make_batched_sim_fn(CFG, resp=resp)
             out = sim(event_keys(jax.random.key(0), range(2)),

@@ -60,7 +60,10 @@ class TestStageMatrix:
         from repro.core.stages import compute_charge_grid
 
         cfg = fit_config(CFG)
-        key = jax.random.key(3)
+        # key 3 (partitionable threefry, JAX 0.9) draws a weight field that
+        # leaves the objective nearly flat in the shaping time (|grad| ~
+        # 8e-4): an f32 central difference is then round-off, not slope
+        key = jax.random.key(4)
         depos = generate_depos(key, cfg)
         grid = compute_charge_grid(jax.random.fold_in(key, 2), depos, cfg)
         w = jax.random.normal(jax.random.fold_in(key, 1), grid.shape)

@@ -16,7 +16,8 @@ import json
 import jax, jax.numpy as jnp, numpy as np
 from repro.parallel.pipeline import pipeline_apply
 
-mesh = jax.make_mesh((4,), ("stage",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("stage",))
 n_stages, n_micro, mb, d = 4, 8, 2, 16
 key = jax.random.key(0)
 w = jax.random.normal(key, (n_stages, d, d)) * 0.3

@@ -43,19 +43,22 @@ CFG3 = dataclasses.replace(CFG, num_planes=3)
 #: strategies draw their own RNG streams (fused kernels: in-kernel counter
 #: hash; multiplane_xla: single-hash erfinv counters), so their digests
 #: differ from the threefry ``unfused`` chain — each pins its own.
+#: Refreshed for JAX 0.9.0: its ``jax_threefry_partitionable`` default
+#: moves every threefry draw (the generator's tracks and the noise feed
+#: every strategy, so all digests moved together).
 GOLDEN_ADC3P_SHA256 = {
     "unfused":
-        "d49fa450d1cca2b86aafffb5d2adc8b96bcf1c1cf200cb0e1255d8e8c9feb4c0",
+        "23a60efbcadc3673ed9e868a286f842a0feffdb993c4a771b19e777cabda5b65",
     "unfused_bf16":
-        "b293a0705c28d3b6fcf59d646488eca11d69297b223084e61ae29a71ee4ae655",
+        "96d3ab6b08adb17c9ebc40a549b73653450c58f6442ced4d59b6f1deee5504d4",
     "fused_pallas":
-        "fe2aebcd5b32f57f3e13e1616f93aafd9754d036e11b0d604f5cacdef2b2ad4f",
+        "5d85362fd00fd6aa73258ac73051300a1554bbfa2cea62092e8621bc975251af",
     "fused_pallas_multiplane":
-        "fe2aebcd5b32f57f3e13e1616f93aafd9754d036e11b0d604f5cacdef2b2ad4f",
+        "5d85362fd00fd6aa73258ac73051300a1554bbfa2cea62092e8621bc975251af",
     "fused_pallas_multiplane_compact":
-        "fe2aebcd5b32f57f3e13e1616f93aafd9754d036e11b0d604f5cacdef2b2ad4f",
+        "5d85362fd00fd6aa73258ac73051300a1554bbfa2cea62092e8621bc975251af",
     "multiplane_xla":
-        "5e10b157d42e84449b3881cff3525173cb55ae23d2045bbaa619908c616cce68",
+        "618782cdd940d8c424e5c9eb9d42f2d424af57a6ec1f33cb23ce01e0be5b0dbb",
 }
 #: strategies that support BOTH dispatch modes (everything except the
 #: multi-plane-only launches, which refuse the per-plane loop)
@@ -207,7 +210,8 @@ from repro.core.distributed import (bin_depos_by_wire, make_distributed_sim,
 results = {}
 cfg3 = LArTPCConfig(num_wires=128, num_ticks=512, num_depos=256,
                     response_wires=11, response_ticks=64, num_planes=3)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 w_pad, _, _ = padded_grid_shape(cfg3, 8)
 resp3 = make_distributed_plane_responses(cfg3, w_pad)
 key = jax.random.key(0)

@@ -79,13 +79,18 @@ class TestProjection:
             np.testing.assert_allclose(got, expect, rtol=1e-5, atol=1e-5)
 
     def test_projection_centered_on_grid(self):
-        """Rotated planes are centered: the bulk of a generated event lands
+        """Rotated planes are centered: the bulk of generated events lands
         inside [0, num_wires) on EVERY plane (only the ±60° corner
-        overhangs that num_wires wires cannot cover may clip)."""
-        pd = generate_physical_depos(jax.random.key(0), CFG3)
-        d = transport_planes(pd, CFG3)
+        overhangs that num_wires wires cannot cover may clip).
+
+        Pooled over 16 events: a smoke event is ONE track, and a single
+        track lands anywhere from 10% to 100% in bounds on a ±60° plane
+        depending on the RNG realization."""
+        wires = [transport_planes(
+            generate_physical_depos(jax.random.key(k), CFG3), CFG3).wire
+            for k in range(16)]
         for p in range(3):
-            w = np.asarray(d.wire[p])
+            w = np.concatenate([np.asarray(x[p]) for x in wires])
             inb = ((w >= 0) & (w <= CFG3.num_wires - 1)).mean()
             assert inb > 0.8, (p, inb)
             # centered: the event's midpoint sits near the grid center

@@ -127,18 +127,29 @@ class TestNoisyHitRecovery:
 
     def test_big_depos_are_found(self):
         """Large-charge depos (well above threshold + noise) each produce
-        at least one nearby hit — the recall side of the round trip."""
-        cfg, depos, out = self._run(seed=2)
-        hits = out.hits
-        hw = np.asarray(hits.wire)[np.asarray(hits.mask)]
-        ht = np.asarray(hits.tick)[np.asarray(hits.mask)]
-        q = np.asarray(depos.charge)
-        big = q > 3000.0
-        assert big.sum() >= 5
-        dw = np.abs(np.asarray(depos.wire)[big][:, None] - hw[None, :]) <= 2.0
-        dt = np.abs(np.asarray(depos.tick)[big][:, None] - ht[None, :]) <= 5.0
-        found = (dw & dt).any(axis=1).mean()
-        assert found > 0.8, f"only {found:.2f} of big depos recovered"
+        at least one nearby hit — the recall side of the round trip.
+
+        Recall is pooled over 8 events: a single event's recall swings
+        between ~0.6 and 1.0 with the RNG realization (a track running
+        along one wire merges into one long run whose mean tick sits more
+        than 5 ticks from most of its depos; a track clipped at the window
+        edge piles depos onto the last tick), so a one-event bound tested
+        the realization, not the recon."""
+        found = []
+        for seed in range(8):
+            cfg, depos, out = self._run(seed=seed)
+            hits = out.hits
+            hw = np.asarray(hits.wire)[np.asarray(hits.mask)]
+            ht = np.asarray(hits.tick)[np.asarray(hits.mask)]
+            big = np.asarray(depos.charge) > 3000.0
+            assert big.sum() >= 5
+            dw = np.abs(np.asarray(depos.wire)[big][:, None]
+                        - hw[None, :]) <= 2.0
+            dt = np.abs(np.asarray(depos.tick)[big][:, None]
+                        - ht[None, :]) <= 5.0
+            found.append((dw & dt).any(axis=1))
+        recall = np.concatenate(found).mean()
+        assert recall > 0.85, f"only {recall:.3f} of big depos recovered"
 
     def test_truncation_is_detectable_not_silent(self):
         """Starving the HitSet capacity shows up as n_hits > mask.sum()."""
@@ -291,7 +302,8 @@ from repro.core.response import make_distributed_response
 
 cfg = LArTPCConfig(num_wires=128, num_ticks=512, num_depos=256,
                    response_wires=11, response_ticks=64, fluctuate=False)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 w_pad, _, _ = padded_grid_shape(cfg, 8)
 resp = make_distributed_response(cfg, w_pad)
 key = jax.random.key(0)

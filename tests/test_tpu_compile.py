@@ -1,0 +1,87 @@
+"""Compile the main path for a described TPU v5e, at full width.
+
+Nothing here runs: each test lowers a program for one chip of a described
+``v5e:2x2`` topology and compiles it with the TPU compiler, which refuses
+what interpret mode cannot see (block shapes, VMEM and memory limits,
+primitives Mosaic cannot lower). Every Pallas kernel that ``"auto"`` or
+``--tune`` can select on a TPU is compiled here with ``interpret=False``;
+the kernels the compiler refuses are excluded on TPU by their availability
+predicates (``core/scatter.py``, ``core/pipeline.py``) and get no test.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU compiler library.
+"""
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.config import get_config
+from repro.core.batch import EventBatch, make_batched_sim_fn
+from repro.kernels.hitfind.ops import find_wire_hits_pallas
+
+FULL = dataclasses.replace(
+    get_config("lartpc-uboone"), charge_grid_strategy="unfused",
+    scatter_strategy="xla", fft_strategy="rfft2", drift_strategy="jnp")
+#: device memory of one TPU v5e chip
+HBM_BYTES = 16 * 10**9
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means no compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _total_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+@pytest.mark.parametrize("num_wires", [FULL.num_wires, FULL.num_wires // 4],
+                         ids=["plane", "quarter_plane"])
+def test_hitfind_kernel_compiles(one_chip, num_wires):
+    """The hit-finder kernel over a full readout window: one plane, and
+    the quarter plane each chip scans when 4 chips split the wires."""
+    decon = jax.ShapeDtypeStruct((num_wires, FULL.num_ticks), jnp.float32,
+                                 sharding=one_chip)
+    fn = jax.jit(lambda d: find_wire_hits_pallas(
+        d, threshold=FULL.hit_threshold, cap=FULL.max_hits_per_wire,
+        interpret=False))
+    compiled = fn.lower(decon).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _total_bytes(compiled) < HBM_BYTES
+
+
+def test_streaming_program_fits_one_chip(one_chip):
+    """The streaming executor's device program (one plane, batch of 2
+    full-size events, donation on) compiles and fits one chip's HBM."""
+    events, n = 2, FULL.num_depos
+    f = jax.ShapeDtypeStruct((events, n), jnp.float32, sharding=one_chip)
+    batch = EventBatch(wire=f, tick=f, sigma_w=f, sigma_t=f, charge=f,
+                       n_depos=jax.ShapeDtypeStruct((events,), jnp.int32,
+                                                    sharding=one_chip))
+    keys = jax.ShapeDtypeStruct((events,), jax.random.key(0).dtype,
+                                sharding=one_chip)
+    sim = make_batched_sim_fn(FULL, donate=True)
+    compiled = sim.lower(keys, batch).compile()
+    # two f32 (N, 24, 128) patch-sized arrays per event: ~5 GB per batch
+    assert 4 * 10**9 < _total_bytes(compiled) < HBM_BYTES

@@ -96,9 +96,19 @@ class TestRegistry:
         ctx = tune.make_context(big, tune.op_shape("scatter_add", big),
                                 backend="cpu")
         assert "pallas" not in tune.available_strategies("scatter_add", ctx)
+        # on TPU the scatter and fused charge-grid kernels are refused by
+        # Mosaic and excluded; the hit-finder kernel compiles and competes
         ctx_tpu = tune.make_context(big, tune.op_shape("scatter_add", big),
                                     backend="tpu")
-        assert "pallas" in tune.available_strategies("scatter_add", ctx_tpu)
+        assert "pallas" not in tune.available_strategies("scatter_add",
+                                                         ctx_tpu)
+        cg = tune.available_strategies(
+            "charge_grid", tune.make_context(
+                big, tune.op_shape("charge_grid", big), backend="tpu"))
+        assert not any(name.startswith("fused") for name in cg)
+        hf = tune.make_context(big, tune.op_shape("hit_find", big),
+                               backend="tpu")
+        assert "pallas" in tune.available_strategies("hit_find", hf)
 
     def test_backend_defaults(self):
         assert tune.default_strategy("scatter_add", "cpu") == "xla"
