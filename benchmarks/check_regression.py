@@ -10,14 +10,14 @@ repo's committed ``BENCH_pipeline.json``:
 
 ``--record`` values may be shell-style globs (fnmatch): a pattern expands
 against the union of baseline and fresh record names, so families of rows —
-e.g. the per-plane stage rows ``'stages/fig4_smoke3p_plane*_total_fused'``
-— are gated without enumerating each plane. A glob must match at least one
+e.g. the calibration rows ``'fit/*'`` — are gated without enumerating
+each one. A glob must match at least one
 *committed baseline* record, else the gate fails loudly: a glob that only
 matches fresh rows is gating nothing (the committed family vanished — or
 was never committed — and every run would silently pass as "(new)").
 
 The diff table ends with a per-``--record`` summary of how many rows each
-selector matched (``gated N record(s) — 'stages/…*': 12, …``), so a family
+selector matched (``gated N record(s) — 'fit/*': 4, …``), so a family
 glob that quietly shrank is visible in the CI log even when every surviving
 row passes.
 

@@ -4,7 +4,6 @@ Prints ``name,us_per_call,derived`` CSV lines. Mapping to the paper:
   rasterization -> Table 2 (+ Table 3 portability note)
   scatter       -> Fig. 5 (scatter-add strategy scaling)
   pipeline      -> Fig. 3 vs Fig. 4 strategies (the headline comparison)
-  stages        -> per-stage cost board (the papers' stage tables)
   fft           -> §5 "FT" stage
   tune          -> per-backend strategy board (registry + autotuner winners)
   lm_step       -> host-framework sanity timings for the 10 assigned archs
@@ -19,14 +18,13 @@ import traceback
 
 def main() -> None:
     from benchmarks import (fft, fit, lm_step, pipeline, rasterization,
-                            scatter, stages, tune)
+                            scatter, tune)
     from benchmarks.common import write_json
     from repro.cache import enable_compile_cache
 
     enable_compile_cache()
     print("name,us_per_call,derived")
-    for mod in [rasterization, scatter, pipeline, stages, fft, tune, lm_step,
-                fit]:
+    for mod in [rasterization, scatter, pipeline, fft, tune, lm_step, fit]:
         try:
             mod.main()
         except Exception:  # noqa: BLE001 — keep the harness going

@@ -8,8 +8,8 @@ object instead of code duplicated across entry points:
 
   Stage     : one named pipeline step — ``fn(SimState) -> SimState`` plus
               the strategy-registry op key it dispatches (if any).
-  SimGraph  : an ordered tuple of stages with one executor (``run``), one
-              instrumentation point per stage boundary (``timed``), and
+  SimGraph  : an ordered tuple of stages with one executor (``run``), which
+              runs each stage under ``jax.named_scope(stage.name)``, and
               stage overrides (``replace``) for specialized executors.
   SimState  : the pytree flowing between stages (keys, depos, grid,
               signal, adc).
@@ -24,9 +24,11 @@ All four production entry points execute the same graph object:
                           with charge_grid/convolve/noise stage overrides)
   stream_simulate       : the double-buffered driver over make_batched_sim_fn
 
-so adding a stage (signal processing / deconvolution is next) or a strategy
-is a one-file change, and the per-stage timing boards the papers use to find
-the next bottleneck come for free (``benchmarks/stages.py``).
+so adding a stage or a strategy is a one-file change, and the per-stage
+cost profile the papers use to find the next bottleneck comes for free: every
+op of a compiled program carries its stage's scope in its HLO ``op_name``
+(``jit(run)/vmap(charge_grid)/scatter-add``), so a profiler trace (TensorBoard,
+Perfetto) attributes device time to stages as the fused program runs.
 
 RNG contract (bit-for-bit with the pre-graph code): the executor splits the
 event key once — ``kf, kn = split(key)`` — exactly as ``simulate_fig4``
@@ -37,7 +39,6 @@ the *unsplit* event key for executors with their own derivation schedule
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -101,7 +102,7 @@ class SimState(NamedTuple):
 class Stage:
     """One named pipeline step.
 
-    name : instrumentation-point name (timing boards key on it)
+    name : the stage's ``jax.named_scope``, which names its ops in a trace
     fn   : ``SimState -> SimState`` — reads its inputs from the state,
            writes its outputs back
     op   : strategy-registry hot-op key this stage dispatches through
@@ -159,47 +160,13 @@ class SimGraph:
 
     def run_state(self, state: SimState) -> SimState:
         for stage in self.stages:
-            state = stage.fn(state)
+            with jax.named_scope(stage.name):
+                state = stage.fn(state)
         return state
 
     def run(self, key: jax.Array, depos) -> SimOutput:
         """Execute the full chain for one event. jit/vmap/shard_map-able."""
         return self.output(self.run_state(self.init_state(key, depos)))
-
-    # -- instrumentation ----------------------------------------------------
-
-    def timed(self, key: jax.Array, depos, *, warmup: int = 1,
-              iters: int = 3, batched: bool = False,
-              ) -> Tuple[SimOutput, Dict[str, float]]:
-        """Run stage-by-stage, timing each stage boundary on device.
-
-        Each stage jits separately and blocks between stages, so the state
-        materializes at every boundary — per-stage cost the way the papers'
-        stage tables report it (the fused end-to-end program can be faster;
-        time ``jit(graph.run)`` for that number). ``batched=True`` vmaps
-        every stage over a leading event axis of ``key``/``depos``.
-
-        Returns (final SimOutput, {stage name: median seconds}).
-        """
-        init = jax.vmap(self.init_state) if batched else self.init_state
-        state = jax.jit(init)(key, depos)
-        jax.block_until_ready(state)
-        timings: Dict[str, float] = {}
-        for stage in self.stages:
-            fn = jax.jit(jax.vmap(stage.fn) if batched else stage.fn)
-            out = fn(state)
-            jax.block_until_ready(out)  # compile + warm
-            for _ in range(max(warmup - 1, 0)):
-                jax.block_until_ready(fn(state))
-            times = []
-            for _ in range(iters):
-                t0 = time.perf_counter()
-                jax.block_until_ready(fn(state))
-                times.append(time.perf_counter() - t0)
-            times.sort()
-            timings[stage.name] = times[len(times) // 2]
-            state = out
-        return self.output(state), timings
 
 
 # ---------------------------------------------------------------------------
@@ -207,11 +174,11 @@ class SimGraph:
 #
 # Multi-plane configs (``cfg.num_planes > 1``) run every readout stage once
 # per plane inside ONE stage fn — a static Python loop over ``plane_specs``
-# with stacked (P, ...) state leaves — so the graph shape, the executors,
-# and the timing boards stay plane-count agnostic. ``planes`` restricts a
-# multi-plane graph to a subset of plane indices (the per-plane cost boards
-# build one-plane graphs this way); it has no effect on single-plane
-# configs, whose stages are byte-for-byte the seed implementations.
+# with stacked (P, ...) state leaves — so the graph shape and the executors
+# stay plane-count agnostic. ``planes`` restricts a multi-plane graph to a
+# subset of plane indices (a one-plane graph of a multi-plane config); it
+# has no effect on single-plane configs, whose stages are byte-for-byte the
+# seed implementations.
 # ---------------------------------------------------------------------------
 
 
@@ -598,16 +565,16 @@ def build_sim_graph(cfg: LArTPCConfig, resp=None,
     None to build the per-plane-type defaults. Multi-plane configs
     (``cfg.num_planes > 1``) run each readout stage per plane and stack a
     leading plane axis onto every ``SimOutput`` leaf; ``planes`` restricts
-    the graph to a subset of plane indices (per-plane cost boards).
+    the graph to a subset of plane indices.
 
     ``add_noise=False`` drops the noise stage (rather than running it as an
-    identity), so timing boards and traced programs only contain real work.
+    identity), so traced programs only contain real work.
     ``overrides`` maps stage names to replacement fns/Stages (see
     ``SimGraph.replace``).
 
     When the config asks for the paper-faithful ``pool`` fluctuation stream
     and no pool is passed, the standard pre-computed pool is built here —
-    every executor (and the timing boards) gets it without its own wiring.
+    every executor gets it without its own wiring.
     (Skipped when ``overrides`` replaces the charge_grid stage: the
     replacement owns its fluctuation scheme, e.g. the distributed
     executor's counter RNG.)

@@ -36,12 +36,22 @@ import dataclasses
 from typing import Any, Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 #: out-of-frame margin, as a multiple of the readout extent: the rasterizer
 #: clips patch origins to the grid, so mildly out-of-range coordinates (the
 #: rotated-plane corner overhangs of a multi-plane projection) are harmless —
 #: the bounds check only rejects values so far out they signal corruption
 FRAME_MARGIN = 4.0
+
+
+def _host_leaves(depos) -> Dict[str, np.ndarray]:
+    """Every leaf of one event, pulled to the host: the ``sim.fetch`` span
+    of a profiler trace. In ``stream_simulate`` the generator's calls have
+    already waited for the batch in flight (``sim.generate``), so this is a
+    short copy of depos the device has produced."""
+    with TraceAnnotation("sim.fetch"):
+        return {f: np.asarray(getattr(depos, f)) for f in depos._fields}
 
 
 def _finite_reasons(name: str, arr: np.ndarray) -> List[str]:
@@ -69,7 +79,7 @@ def check_physical_depos(pdepos, cfg, max_depos: Optional[int] = None
     anode); arrival tick ``(t + x) / tick_us`` within ``FRAME_MARGIN``
     readout windows; optional depo-count ceiling ``max_depos``.
     """
-    leaves = {f: np.asarray(getattr(pdepos, f)) for f in pdepos._fields}
+    leaves = _host_leaves(pdepos)
     reasons: List[str] = []
     reasons += _shape_reasons(leaves, num_planes=1)  # physical frame: no
     #                                                  plane axis yet
@@ -103,7 +113,7 @@ def check_detector_depos(depos, cfg, max_depos: Optional[int] = None
     depo-count ceiling ``max_depos`` (the padded batch capacity — an event
     bigger than the pad target would crash ``pack_events``).
     """
-    leaves = {f: np.asarray(getattr(depos, f)) for f in depos._fields}
+    leaves = _host_leaves(depos)
     reasons = _shape_reasons(leaves, num_planes=cfg.num_planes)
     for name, arr in leaves.items():
         reasons += _finite_reasons(name, arr)

@@ -2,29 +2,33 @@
 
     python -m repro.launch.sim [--smoke] [--events N] [--batch-events E]
                                [--pipeline fig3|fig4] [--tune] [--retune]
-                               [--strategy <scatter>] [--stage-board]
+                               [--strategy <scatter>] [--trace-dir DIR]
                                [--recon] [--set key=value ...]
 
 ``--tune`` autotunes every registered hot op (drift, scatter-add,
 charge-grid, FFT-convolve) on the live backend at this config's shape before
 running, caching winners to disk; a repeated run reports cache hits instead
 of re-measuring (see docs/tuning.md). ``--strategy`` forces the scatter-add
-strategy, overriding both the config and the tuner. ``--stage-board`` prints
-per-stage device timings (the papers' stage-cost table) before streaming.
+strategy, overriding both the config and the tuner. ``--trace-dir`` records
+a profiler trace of the stream (TensorBoard/Perfetto): device time per stage
+under each stage's named scope, and the host's ``sim.*`` spans per batch.
 ``--recon`` closes the sim->recon loop: the streamed graph also deconvolves
 the ADC and finds hits, and each batch reports its hit counts.
 
 The fig4 path streams *batches* of events through one vmap'd device program
-(``repro.core.batch``): while batch b computes on device, the host generates
-and stages batch b+1 (double buffering), so H2D transfer and host-side event
-generation overlap with device compute — the paper's "minimize data movement"
-prescription applied at the event level. ``--batch-events 1`` degenerates to
+(``repro.core.batch``): while batch b computes on device, the host prepares
+batch b+1 and then copies batch b back (double buffering) — the paper's
+"minimize data movement" prescription applied at the event level. The
+generator's ops run eagerly on the device, so on a TPU they queue behind
+batch b: the ``sim.*`` spans of a ``--trace-dir`` trace show where each
+step waits. ``--batch-events 1`` degenerates to
 the classic one-event-per-launch loop; fig3 keeps the faithful per-depo
 host-loop baseline.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import hashlib
 import time
 import warnings
@@ -32,6 +36,7 @@ from typing import Callable, Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.cache import enable_compile_cache
 from repro.config import LArTPCConfig, apply_overrides, get_config
@@ -83,7 +88,8 @@ def stream_simulate(cfg: LArTPCConfig, num_events: int, batch_events: int = 1,
     ``make_batched_sim_fn``'s jit'd vmap over ``SimGraph.run``).
 
     Pipelined schedule per step b:
-      1. host generates + packs batch b            (overlaps device batch b-1)
+      1. host generates + packs batch b            (eager generator ops
+                                                    queue behind batch b-1)
       2. ``shard_events`` stages batch b to device (async H2D)
       3. dispatch ``sim(keys, batch_b)``           (async — device now busy)
       4. block on batch b-1's result and report it
@@ -92,6 +98,13 @@ def stream_simulate(cfg: LArTPCConfig, num_events: int, batch_events: int = 1,
     same static (E, N_max) shape — one trace, no re-jit. Returns aggregate
     stats: events, depos, wall_s, per-batch records, plus a ``health`` dict
     (``repro.core.validate.RunHealth``) of fault-tolerance counters.
+
+    Every step runs under a host span (``jax.profiler.TraceAnnotation``,
+    free when no trace is recording), each tagged ``batch=b``:
+    ``sim.generate`` (events), ``sim.screen`` (events, quarantined; the
+    device-to-host pull of each event inside it is ``sim.fetch``),
+    ``sim.pack`` (depos, slots), ``sim.dispatch``, ``sim.wait`` (blocking on
+    the batch and its readbacks), ``sim.journal`` and ``sim.callback``.
 
     Fault tolerance (docs/robustness.md):
 
@@ -153,13 +166,17 @@ def stream_simulate(cfg: LArTPCConfig, num_events: int, batch_events: int = 1,
         """
         ids = list(range(b * batch_events,
                          min((b + 1) * batch_events, num_events)))
-        events = [gen(jax.random.fold_in(key, ev), cfg) for ev in ids]
+        with TraceAnnotation("sim.generate", batch=b, events=len(ids)):
+            events = [gen(jax.random.fold_in(key, ev), cfg) for ev in ids]
         if faults is not None:
             events = [faults.corrupt_event(ev, d)
                       for ev, d in zip(ids, events)]
         if validate:
-            events, ids, _ = screen_events(events, ids, cfg, pad_to=pad_to,
-                                           batch=b, health=health)
+            with TraceAnnotation("sim.screen", batch=b,
+                                 events=len(ids)) as span:
+                events, ids, letters = screen_events(
+                    events, ids, cfg, pad_to=pad_to, batch=b, health=health)
+                span.set_metadata(quarantined=len(letters))
         n_valid = len(ids)
         rows = events + [empty_event(planes=cfg.num_planes)] * (
             batch_events - n_valid)
@@ -168,15 +185,18 @@ def stream_simulate(cfg: LArTPCConfig, num_events: int, batch_events: int = 1,
             num_events + b * batch_events + batch_events - n_valid))
         return rows, row_ids, n_valid
 
-    def launch_rows(b: int, rows, row_ids):
-        """One device launch over the given event rows (fresh keys + fresh
-        packed buffers every time, so donation can never invalidate a
-        retry's inputs)."""
+    def launch_rows(b: int, rows, row_ids, n_depos: int):
+        """One device launch over the given event rows, holding ``n_depos``
+        depos (fresh keys + fresh packed buffers every time, so donation can
+        never invalidate a retry's inputs)."""
         if faults is not None:
             faults.before_dispatch(b)
-        keys = event_keys(key, row_ids)
-        batch = shard_events(pack_events(rows, pad_to=pad_to))
-        return sim(keys, batch)
+        with TraceAnnotation("sim.pack", batch=b, depos=n_depos,
+                             slots=len(rows) * pad_to):
+            keys = event_keys(key, row_ids)
+            batch = shard_events(pack_events(rows, pad_to=pad_to))
+        with TraceAnnotation("sim.dispatch", batch=b):
+            return sim(keys, batch)
 
     def run_degraded(b: int, rows, row_ids, first_exc: BaseException):
         """Bounded retry with graceful degradation: halve the event count
@@ -202,7 +222,9 @@ def stream_simulate(cfg: LArTPCConfig, num_events: int, batch_events: int = 1,
             try:
                 outs = []
                 for s in range(0, len(rows), sub):
-                    o = launch_rows(b, rows[s:s + sub], row_ids[s:s + sub])
+                    part = rows[s:s + sub]
+                    o = launch_rows(b, part, row_ids[s:s + sub],
+                                    sum(int(d.n) for d in part))
                     jax.block_until_ready(o.adc)
                     outs.append(o)
                 if len(outs) == 1:
@@ -218,37 +240,41 @@ def stream_simulate(cfg: LArTPCConfig, num_events: int, batch_events: int = 1,
 
     def finish(entry):
         b, rows, row_ids, n_valid, n_depos, t0, out = entry
-        try:
-            jax.block_until_ready(out.adc)
-        except Exception as e:  # noqa: BLE001 — run_degraded classifies
-            out = run_degraded(b, rows, row_ids, e)
-        dt = time.perf_counter() - t0
-        # record the batch BEFORE the user callback runs: a callback
-        # exception must not lose the batch's stats or journal entry
-        health.events_ok += n_valid
-        stats["events"] += n_valid
-        stats["depos"] += n_depos
-        rec = {"batch": b, "events": n_valid, "depos": n_depos, "wall_s": dt}
-        if out.finite_ok is not None:
-            bad = int(np.count_nonzero(
-                ~np.asarray(out.finite_ok)[:n_valid]))
-            rec["nonfinite"] = bad
-            health.nonfinite_events += bad
-        if recon and out.hits is not None:
-            rec["hits"] = int(np.asarray(out.hits.mask[:n_valid]).sum())
+        with TraceAnnotation("sim.wait", batch=b):
+            try:
+                jax.block_until_ready(out.adc)
+            except Exception as e:  # noqa: BLE001 — run_degraded classifies
+                out = run_degraded(b, rows, row_ids, e)
+            dt = time.perf_counter() - t0
+            # record the batch BEFORE the user callback runs: a callback
+            # exception must not lose the batch's stats or journal entry
+            health.events_ok += n_valid
+            stats["events"] += n_valid
+            stats["depos"] += n_depos
+            rec = {"batch": b, "events": n_valid, "depos": n_depos,
+                   "wall_s": dt}
+            if out.finite_ok is not None:
+                bad = int(np.count_nonzero(
+                    ~np.asarray(out.finite_ok)[:n_valid]))
+                rec["nonfinite"] = bad
+                health.nonfinite_events += bad
+            if recon and out.hits is not None:
+                rec["hits"] = int(np.asarray(out.hits.mask[:n_valid]).sum())
         if jrn is not None:
-            adc = np.ascontiguousarray(np.asarray(out.adc[:n_valid]))
-            jrec = dict(rec, ids=[int(i) for i in row_ids[:n_valid]],
-                        adc_sha=hashlib.sha256(adc.tobytes()).hexdigest(),
-                        quarantined=sum(
-                            1 for d in health.dead_letters
-                            if d["batch"] == b))
-            jrec.pop("wall_s")
-            jrn.append_batch(jrec)
+            with TraceAnnotation("sim.journal", batch=b):
+                adc = np.ascontiguousarray(np.asarray(out.adc[:n_valid]))
+                jrec = dict(rec, ids=[int(i) for i in row_ids[:n_valid]],
+                            adc_sha=hashlib.sha256(adc.tobytes()).hexdigest(),
+                            quarantined=sum(
+                                1 for d in health.dead_letters
+                                if d["batch"] == b))
+                jrec.pop("wall_s")
+                jrn.append_batch(jrec)
         stats["batches"].append(rec)
         if on_batch is not None:
             try:
-                on_batch(b, n_valid, n_depos, dt, out)
+                with TraceAnnotation("sim.callback", batch=b):
+                    on_batch(b, n_valid, n_depos, dt, out)
             except Exception as e:  # noqa: BLE001 — user code, not ours
                 health.callback_errors += 1
                 warnings.warn(
@@ -268,12 +294,13 @@ def stream_simulate(cfg: LArTPCConfig, num_events: int, batch_events: int = 1,
                     "depos": int(done.get("depos", 0)), "wall_s": 0.0,
                     "resumed": True})
                 continue
-            rows, row_ids, n_valid = make_batch(b)  # host gen (overlaps b-1)
+            rows, row_ids, n_valid = make_batch(b)  # queues behind b-1
             n_depos = sum(int(d.n) for d in rows[:n_valid])
             t0 = time.perf_counter()
             try:
                 try:
-                    out = launch_rows(b, rows, row_ids)  # async dispatch
+                    # async dispatch
+                    out = launch_rows(b, rows, row_ids, n_depos)
                 except Exception as e:  # noqa: BLE001 — classified below
                     out = run_degraded(b, rows, row_ids, e)
             except SimBatchError:
@@ -333,11 +360,10 @@ def main():
     ap.add_argument("--strategy", default=None,
                     help="force the scatter-add strategy (see repro.tune; "
                          "'auto' resolves via the tuning cache)")
-    ap.add_argument("--stage-board", action="store_true",
-                    help="print per-stage device timings for this config "
-                         "before streaming (drift/charge_grid/convolve/"
-                         "noise/digitize, plus deconvolve/hit_find "
-                         "with --recon)")
+    ap.add_argument("--trace-dir", default=None, metavar="DIR",
+                    help="record a profiler trace of the stream into DIR "
+                         "(TensorBoard/Perfetto): device time per stage "
+                         "scope and the host's sim.* spans per batch")
     ap.add_argument("--recon", action="store_true",
                     help="append the deconvolve + hit_find recon stages "
                          "and report per-batch hit counts (fig4 only)")
@@ -397,29 +423,6 @@ def main():
                              f"known: {known}")
         cfg = apply_overrides(cfg, {"scatter_strategy": args.strategy})
 
-    if args.stage_board:
-        from repro.core import build_sim_graph, generate_physical_depos
-        from repro.tune import resolve_config
-
-        rcfg = resolve_config(cfg)
-        graph = build_sim_graph(rcfg, recon=args.recon)
-        key = jax.random.key(args.seed)
-        pdepos = generate_physical_depos(key, rcfg)
-        _, timings = graph.timed(key, pdepos)
-        total = sum(timings.values())
-        for name, sec in timings.items():
-            print(f"stage {name:<12} {sec * 1e3:8.2f} ms "
-                  f"({100 * sec / total:5.1f}%)")
-        if rcfg.num_planes > 1:
-            # per-plane rows — the papers' per-plane cost tables: the same
-            # graph restricted to one plane at a time
-            for p in range(rcfg.num_planes):
-                _, pt = build_sim_graph(rcfg, planes=(p,),
-                                        recon=args.recon).timed(key, pdepos)
-                for name, sec in pt.items():
-                    print(f"stage plane{p}/{name:<10} {sec * 1e3:8.2f} ms "
-                          f"({100 * sec / total:5.1f}%)")
-
     faults = None
     if args.inject_faults:
         from repro.testing.faults import FaultPlan
@@ -430,7 +433,7 @@ def main():
         if args.recon:
             raise SystemExit("--recon needs the batched fig4 pipeline "
                              "(drop --pipeline fig3)")
-        for flag in ("journal", "resume", "inject_faults"):
+        for flag in ("journal", "resume", "inject_faults", "trace_dir"):
             if getattr(args, flag):
                 raise SystemExit(f"--{flag.replace('_', '-')} needs the "
                                  "batched fig4 pipeline (drop "
@@ -459,18 +462,25 @@ def main():
                      + (f" ({found} found)" if found != stored else ""))
         print(line)
 
+    tracing = (jax.profiler.trace(args.trace_dir) if args.trace_dir
+               else contextlib.nullcontext())
     try:
-        stats = stream_simulate(cfg, args.events, args.batch_events,
-                                seed=args.seed, on_batch=report,
-                                recon=args.recon, journal=args.journal,
-                                resume=args.resume,
-                                validate=not args.no_validate,
-                                max_retries=args.max_retries, faults=faults)
+        with tracing:
+            stats = stream_simulate(cfg, args.events, args.batch_events,
+                                    seed=args.seed, on_batch=report,
+                                    recon=args.recon, journal=args.journal,
+                                    resume=args.resume,
+                                    validate=not args.no_validate,
+                                    max_retries=args.max_retries,
+                                    faults=faults)
     except SimBatchError as e:
         raise SystemExit(
             f"stream failed: {e}" + ("" if not args.journal else
                                      f" — rerun with --resume to continue "
                                      f"from the journal at {args.journal}"))
+    if args.trace_dir:
+        print(f"trace: {args.trace_dir} (TensorBoard's profile plugin or "
+              "Perfetto: stage scopes on the device, sim.* spans on the host)")
     ev_s = stats["events"] / stats["wall_s"]
     dp_s = stats["depos"] / stats["wall_s"]
     print(f"total: {stats['events']} events / {stats['depos']} depos in "

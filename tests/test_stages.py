@@ -20,7 +20,8 @@ from repro.core.batch import event_keys, make_batched_sim_fn, pack_events
 from repro.core.depo import generate_depos, generate_physical_depos
 from repro.core.pipeline import make_sim_fn, simulate, simulate_fig4
 from repro.core.response import make_response
-from repro.core.stages import STAGE_ORDER, build_sim_graph
+from repro.core.stages import (FULL_STAGE_ORDER, STAGE_ORDER,
+                                 build_sim_graph)
 
 CFG = get_config("lartpc-uboone", smoke=True)
 
@@ -214,29 +215,78 @@ class TestGraphMechanics:
         assert ops["noise"] is None and ops["digitize"] is None
 
 
-class TestTimed:
-    def test_timed_covers_every_stage_and_matches_run(self):
-        graph = build_sim_graph(CFG, make_response(CFG))
-        key = jax.random.key(0)
-        pdepos = generate_physical_depos(key, CFG)
-        out, timings = graph.timed(key, pdepos, warmup=0, iters=1)
-        assert tuple(timings) == graph.stage_names
-        assert all(t >= 0 for t in timings.values())
-        ref = jax.jit(graph.run)(key, pdepos)
-        np.testing.assert_array_equal(np.asarray(out.adc),
-                                      np.asarray(ref.adc))
+def _op_names(compiled_text: str):
+    """Every ``op_name`` (JAX source path) of a compiled program's HLO."""
+    return set(re.findall(r'op_name="([^"]*)"', compiled_text))
 
-    def test_timed_batched(self):
-        graph = build_sim_graph(CFG, make_response(CFG))
+
+def _scopes(op_names, names):
+    """The stage names that occur as a whole element of some op's path."""
+    return {n for n in names
+            if any(re.search(r"(?:^|[/(])" + n + r"(?:[/)]|$)", p)
+                   for p in op_names)}
+
+
+def _stage_program(kind):
+    """(compiled HLO text, stages that do work in it) at the smoke size."""
+    from repro.config import apply_overrides
+    from repro.core.depo import generate_plane_depos
+
+    key = jax.random.key(0)
+    if kind == "single":
+        # physical input: the drift stage transports it inside the program
+        pdepos = generate_physical_depos(key, CFG)
+        text = make_sim_fn(CFG).lower(key, pdepos).compile().as_text()
+        return text, STAGE_ORDER
+    cfg = CFG if kind != "stacked3p" else apply_overrides(
+        CFG, {"num_planes": 3, "plane_batching": "stacked"})
+    gen = generate_plane_depos if cfg.num_planes > 1 else generate_depos
+    events = [gen(jax.random.fold_in(key, e), cfg) for e in range(2)]
+    recon = kind == "recon"
+    fn = make_batched_sim_fn(cfg, recon=recon)
+    text = fn.lower(event_keys(key, range(2)),
+                    pack_events(events)).compile().as_text()
+    # packed batches are detector-frame depos: drift passes them through
+    stages = tuple(s for s in (FULL_STAGE_ORDER if recon else STAGE_ORDER)
+                   if s != "drift")
+    return text, stages
+
+
+class TestStageScopes:
+    """Every executor runs each stage under ``jax.named_scope(stage.name)``,
+    so a trace of the fused program attributes device time to stages."""
+
+    @pytest.mark.parametrize("kind",
+                             ["single", "batched", "recon", "stacked3p"])
+    def test_compiled_program_carries_every_stage_scope(self, kind):
+        text, stages = _stage_program(kind)
+        names = _op_names(text)
+        assert _scopes(names, FULL_STAGE_ORDER) == set(stages)
+
+    def test_op_kinds_keep_their_names_inside_the_scopes(self):
+        """The scatter-add sits in charge_grid and the transforms in
+        convolve, with the op's own name still in its path."""
+        text, _ = _stage_program("batched")
+        names = _op_names(text)
+        assert any("vmap(charge_grid)/" in p and "/scatter" in p
+                   for p in names)
+        assert any("vmap(convolve)/" in p and "jit(fft)" in p
+                   for p in names)
+
+    def test_scopes_leave_the_pinned_digest(self):
+        """The scopes are metadata only: the batched program under them
+        still gives the pinned ADC digest."""
+        if jax.default_backend() != "cpu":
+            pytest.skip("pinned digests are CPU-lowering specific")
         key = jax.random.key(0)
-        events = [generate_physical_depos(jax.random.fold_in(key, e), CFG)
+        events = [generate_depos(jax.random.fold_in(key, e), CFG)
                   for e in range(2)]
-        batch = jax.tree.map(lambda *xs: jax.numpy.stack(xs), *events)
-        keys = event_keys(key, range(2))
-        out, timings = graph.timed(keys, batch, warmup=0, iters=1,
-                                   batched=True)
-        assert tuple(timings) == graph.stage_names
-        assert np.asarray(out.adc).shape == (2, CFG.num_wires, CFG.num_ticks)
+        compiled = make_batched_sim_fn(CFG).lower(
+            event_keys(key, range(2)), pack_events(events)).compile()
+        assert _scopes(_op_names(compiled.as_text()), STAGE_ORDER) == (
+            set(STAGE_ORDER) - {"drift"})
+        out = compiled(event_keys(key, range(2)), pack_events(events))
+        assert _sha(out.adc) == GOLDEN_BATCHED_E2_SHA256
 
 
 if __name__ == "__main__":
