@@ -177,8 +177,12 @@ class LArTPCConfig:
     #           the pipeline is finite (see docs/calibration.md)
     # none    : no fluctuation
     rng_strategy: str = "counter"  # counter | pool | relaxed | none
-    # xla: one scatter HLO (best single-device default);
-    # sort_segment: sorted sequential-traffic form (TPU-oriented);
+    # xla: one scatter HLO with a (pw, pt) window per depo (the CPU's
+    #   default; the TPU compiler turns it into one loop trip per depo);
+    # lane_rows: patch rows placed in 128-tick rows and added by one row
+    #   scatter per chunk, which the TPU sorts and applies natively (the
+    #   TPU's default);
+    # sort_segment: sorted sequential-traffic form;
     # pallas: owner-computes tile kernel (dense tile grid);
     # pallas_compact: owner-computes over OCCUPIED tiles only;
     # auto: resolve via the kernel-strategy registry / tuning cache

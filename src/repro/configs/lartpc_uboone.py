@@ -3,7 +3,9 @@ from repro.config import LArTPCConfig, register
 
 
 def full() -> LArTPCConfig:
-    return LArTPCConfig()  # 2560 wires x 9592 ticks, 100k depos
+    # 2560 wires x 9592 ticks, 100k depos; the scatter strategy follows the
+    # backend's default (lane_rows on a TPU, xla elsewhere)
+    return LArTPCConfig(scatter_strategy="auto")
 
 
 def smoke() -> LArTPCConfig:

@@ -7,12 +7,16 @@ primitives Mosaic cannot lower). Every Pallas kernel that ``"auto"`` or
 ``--tune`` can select on a TPU is compiled here with ``interpret=False``;
 the kernels the compiler refuses are excluded on TPU by their availability
 predicates (``core/scatter.py``, ``core/pipeline.py``) and get no test.
+The streaming program is compiled with the window scatter and with the
+TPU's default scatter, and its optimized HLO records which of them leaves
+a loop with one trip per patch.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler library.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +26,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.config import get_config
 from repro.core.batch import EventBatch, make_batched_sim_fn
 from repro.kernels.hitfind.ops import find_wire_hits_pallas
+from repro.tune import default_strategy
 
 FULL = dataclasses.replace(
     get_config("lartpc-uboone"), charge_grid_strategy="unfused",
@@ -71,17 +76,56 @@ def test_hitfind_kernel_compiles(one_chip, num_wires):
     assert _total_bytes(compiled) < HBM_BYTES
 
 
-def test_streaming_program_fits_one_chip(one_chip):
+@pytest.fixture(scope="module")
+def streaming_program(one_chip):
     """The streaming executor's device program (one plane, batch of 2
-    full-size events, donation on) compiles and fits one chip's HBM."""
-    events, n = 2, FULL.num_depos
-    f = jax.ShapeDtypeStruct((events, n), jnp.float32, sharding=one_chip)
-    batch = EventBatch(wire=f, tick=f, sigma_w=f, sigma_t=f, charge=f,
-                       n_depos=jax.ShapeDtypeStruct((events,), jnp.int32,
-                                                    sharding=one_chip))
-    keys = jax.ShapeDtypeStruct((events,), jax.random.key(0).dtype,
-                                sharding=one_chip)
-    sim = make_batched_sim_fn(FULL, donate=True)
-    compiled = sim.lower(keys, batch).compile()
-    # two f32 (N, 24, 128) patch-sized arrays per event: ~5 GB per batch
-    assert 4 * 10**9 < _total_bytes(compiled) < HBM_BYTES
+    full-size events, donation on) for a scatter strategy, compiled once."""
+    compiled = {}
+
+    def compile_for(strategy):
+        if strategy not in compiled:
+            events, n = 2, FULL.num_depos
+            f = jax.ShapeDtypeStruct((events, n), jnp.float32,
+                                     sharding=one_chip)
+            batch = EventBatch(
+                wire=f, tick=f, sigma_w=f, sigma_t=f, charge=f,
+                n_depos=jax.ShapeDtypeStruct((events,), jnp.int32,
+                                             sharding=one_chip))
+            keys = jax.ShapeDtypeStruct((events,), jax.random.key(0).dtype,
+                                        sharding=one_chip)
+            cfg = dataclasses.replace(FULL, scatter_strategy=strategy)
+            sim = make_batched_sim_fn(cfg, donate=True)
+            compiled[strategy] = sim.lower(keys, batch).compile()
+        return compiled[strategy]
+
+    return compile_for
+
+
+@pytest.mark.parametrize("strategy", ["xla", "lane_rows"])
+def test_streaming_program_fits_one_chip(streaming_program, strategy):
+    """The streaming program fits one chip's HBM, with the window scatter
+    and with the TPU's default scatter."""
+    total = _total_bytes(streaming_program(strategy))
+    assert total < HBM_BYTES
+    if strategy == "xla":
+        # two f32 (N, 24, 128) patch-sized arrays per event: ~5 GB per batch
+        assert 4 * 10**9 < total
+
+
+@pytest.mark.parametrize("strategy,per_patch_loop",
+                         [("xla", True), ("lane_rows", False)])
+def test_charge_grid_scatter_loop(streaming_program, strategy,
+                                  per_patch_loop):
+    """The TPU compiler expands the window scatter into a ``while`` loop
+    with one trip per patch (named after the scatter-add it replaces); the
+    TPU's default scatter leaves no such loop, only its chunk loop."""
+    assert default_strategy("scatter_add", "tpu") == "lane_rows"
+    loops = []
+    for line in streaming_program(strategy).as_text().splitlines():
+        if " while(" not in line:
+            continue
+        name = re.search(r'op_name="([^"]*)"', line)
+        if name and "charge_grid" in name.group(1):
+            loops.append(name.group(1))
+    assert any("scatter-add" in op for op in loops) == per_patch_loop, loops
+    assert loops, "the charge grid keeps a loop: per patch or per chunk"

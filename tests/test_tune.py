@@ -26,8 +26,8 @@ CFG = LArTPCConfig(num_wires=96, num_ticks=768, num_depos=64)
 
 #: fake timings (seconds) — pallas / fused_pallas are made the deterministic
 #: winners on purpose: the wall clock must play no part under an injected timer
-FAKE_TIMES = {"xla": 3.0, "sort_segment": 2.0, "pallas": 1.0,
-              "pallas_compact": 1.5,
+FAKE_TIMES = {"xla": 3.0, "sort_segment": 2.0, "lane_rows": 2.5,
+              "pallas": 1.0, "pallas_compact": 1.5,
               "unfused": 2.0, "unfused_bf16": 2.5, "fused_pallas": 1.0,
               "fused_pallas_compact": 1.5, "rfft2": 1.0, "fft2": 2.0,
               "scan": 2.0}  # hit_find: "pallas" (1.0) fake-wins over "scan"
@@ -61,7 +61,7 @@ class TestRegistry:
         assert set(tune.list_ops()) >= {"scatter_add", "charge_grid",
                                         "fft_convolve"}
         assert set(tune.strategies("scatter_add")) == {
-            "xla", "sort_segment", "pallas", "pallas_compact"}
+            "xla", "sort_segment", "lane_rows", "pallas", "pallas_compact"}
         assert set(tune.strategies("charge_grid")) == {
             "unfused", "unfused_bf16", "fused_pallas",
             "fused_pallas_compact", "fused_pallas_multiplane",
@@ -112,6 +112,7 @@ class TestRegistry:
 
     def test_backend_defaults(self):
         assert tune.default_strategy("scatter_add", "cpu") == "xla"
+        assert tune.default_strategy("scatter_add", "tpu") == "lane_rows"
         assert tune.default_strategy("fft_convolve", "tpu") == "rfft2"
 
 
@@ -123,7 +124,7 @@ class TestAutotuner:
                          timer=fake_timer(calls))
         assert d.strategy == "pallas"      # smallest fake time, not wall time
         assert d.source == "tuned"
-        assert set(calls) == {"xla", "sort_segment", "pallas",
+        assert set(calls) == {"xla", "sort_segment", "lane_rows", "pallas",
                               "pallas_compact"}
 
     def test_cache_roundtrip_second_call_hits_disk(self, tmp_path):
@@ -224,6 +225,7 @@ class TestStrategyEquivalence:
         patches, w0, t0 = rasterize(depos, CFG)
         grids = {name: np.asarray(strat.fn(patches, w0, t0, CFG))
                  for name, strat in tune.strategies("scatter_add").items()}
+        assert "lane_rows" in grids
         ref_name, ref = next(iter(grids.items()))
         assert float(np.abs(ref).sum()) > 0.0
         for name, grid in grids.items():
@@ -236,6 +238,7 @@ class TestStrategyEquivalence:
         grids = {name: np.asarray(strat.fn(patches, w0, t0, CFG))
                  for name, strat in tune.strategies("scatter_add").items()}
         ref = grids.pop("xla")
+        assert "lane_rows" in grids
         for name, grid in grids.items():
             np.testing.assert_allclose(grid, ref, rtol=1e-4, atol=5e-2,
                                        err_msg=name)
