@@ -1,9 +1,9 @@
 """The comparison that decides ``correct``.
 
 After the window has closed, one batch of the window, drawn from the seed,
-is compared event by event with the plain reference (``reference.py``),
-stage by stage along the chain the timed program ran, each stage fed by the
-reference's own previous stage:
+is compared event by event with the plain reference, stage by stage along
+the chain the timed program ran, each stage fed by the reference's own
+previous stage:
 
     grid_err      charge grid after fluctuation  ||prog - ref|| / ||ref||
     signal_err    after convolution and noise    ||prog - ref|| / ||ref||
@@ -11,19 +11,35 @@ reference's own previous stage:
     decon_err     deconvolved charge (recon)     ||prog - ref|| / ||ref||
     hit_mismatch  stored hits (recon)            share without a partner
 
-Norms run over all planes of an event; each number is the worst event of
-the batch. A number passes when it is at most its limit from the cell's
-file (``bench/cells/<cell>.json``); ``PERF.md`` gives the readings each
-limit was set from.
+Both sides are taken plane by plane, as lists with one array per plane, so
+planes may differ in wire count. Norms and shares run over all planes of
+an event; hits are partnered within their plane; each number is the worst
+event of the batch. A number passes when it is at most its limit from the
+cell's file (``bench/cells/<cell>.json``); ``PERF.md`` gives the readings
+each limit was set from.
+
+The reference is found through a module with three names: ``GENERATORS``
+(the frozen event generators), ``reference_event`` (one event's outputs,
+per plane, by the deployment's key schedule) and ``event_view`` (one event
+of the program's batch, per plane). A configuration names its own module
+under ``bench/references``; one that names none is checked by this module's
+own three: ``depogen``'s generators, the plain reference's default
+geometry (``reference.py``) with ``num_wires`` wires on every plane, and
+the program's (E[, P], W, T) outputs.
 """
 from __future__ import annotations
 
 from concurrent.futures import ThreadPoolExecutor
-from typing import Dict, List
+from typing import TYPE_CHECKING, Dict, List
 
 import numpy as np
 
-from bench import depogen, reference
+from bench import depogen
+
+if TYPE_CHECKING:  # SciPy's import takes seconds: the check imports it
+    from bench import reference  # after the window, never in set-up
+
+GENERATORS = depogen.GENERATORS
 
 #: a program hit partners a reference hit on the same wire when its mean
 #: tick and charge agree this closely
@@ -38,7 +54,8 @@ def sample_batch(seed: int, n_batches: int) -> int:
 
 def reference_event(seed: int, event_id: int, sizes: dict, n_depos: int,
                     generator: str, recon: bool) -> reference.EventRef:
-    """The reference's outputs for one event of the stream ``seed``.
+    """The default reference's outputs for one event of the stream
+    ``seed``, per plane.
 
     The depos come from the frozen generator on the default device, as the
     program's stream draws them there; the normals from ``jax.random`` on
@@ -46,8 +63,10 @@ def reference_event(seed: int, event_id: int, sizes: dict, n_depos: int,
     import jax
     import jax.numpy as jnp
 
+    from bench import reference
+
     key = depogen.event_key(seed, event_id)
-    phys = depogen.GENERATORS[generator](key, n_depos, sizes)
+    phys = GENERATORS[generator].draw(key, n_depos, sizes)
     phys = {f: np.asarray(getattr(phys, f)) for f in phys._fields}
     cpu = jax.devices("cpu")[0]
     with jax.default_device(cpu):
@@ -67,23 +86,50 @@ def reference_event(seed: int, event_id: int, sizes: dict, n_depos: int,
     return reference.simulate_event(phys, draws, sizes, recon)
 
 
-def rel_l2(prog: np.ndarray, ref: np.ndarray) -> float:
-    d = prog.astype(np.float64) - ref
-    return float(np.sqrt(np.sum(d * d)) / max(np.sqrt(np.sum(ref * ref)),
-                                               1e-30))
+def event_view(batch_out: dict, i: int, sizes: dict) -> dict:
+    """Event ``i`` of a batch as per-plane lists: the program's leaves are
+    (E, W, T) for one plane and (E, P, W, T) for several; its hits become
+    one dict of leaves per plane."""
+    n = sizes["num_planes"]
+
+    def planes(x):
+        return [x[i]] if n == 1 else list(x[i])
+
+    view = {k: planes(v) for k, v in batch_out.items() if k != "hits"}
+    if batch_out.get("hits") is not None:
+        leaves = {k: planes(v) for k, v in batch_out["hits"].items()}
+        view["hits"] = [{k: v[p] for k, v in leaves.items()}
+                        for p in range(n)]
+    return view
 
 
-def _plane_hits(hits: dict, p: int):
-    mask = hits["mask"][p]
-    return (hits["wire"][p][mask], hits["tick"][p][mask],
-            hits["charge"][p][mask])
+def rel_l2(prog: List[np.ndarray], ref: List[np.ndarray]) -> float:
+    """sqrt(sum_p ||prog_p - ref_p||^2) / sqrt(sum_p ||ref_p||^2)."""
+    num = den = 0.0
+    for a, b in zip(prog, ref, strict=True):
+        d = a.astype(np.float64) - b
+        num += np.sum(d * d)
+        den += np.sum(b * b)
+    return float(np.sqrt(num) / max(np.sqrt(den), 1e-30))
 
 
-def hit_mismatch(prog_hits: dict, ref_hits: List[reference.Hits]) -> float:
+def mismatch_share(prog: List[np.ndarray], ref: List[np.ndarray]) -> float:
+    """Pixels that differ over all pixels, across planes."""
+    return float(np.mean(np.concatenate(
+        [np.ravel(a != b) for a, b in zip(prog, ref, strict=True)])))
+
+
+def _plane_hits(hits: dict):
+    mask = hits["mask"]
+    return hits["wire"][mask], hits["tick"][mask], hits["charge"][mask]
+
+
+def hit_mismatch(prog_hits: List[dict], ref_hits: List[reference.Hits]
+                 ) -> float:
     """Share of stored hits, on both sides, with no partner on the other."""
     unmatched = total = 0
-    for p, ref in enumerate(ref_hits):
-        pw, pt, pq = _plane_hits(prog_hits, p)
+    for plane, ref in zip(prog_hits, ref_hits, strict=True):
+        pw, pt, pq = _plane_hits(plane)
         total += len(pw) + len(ref.wire)
         used = np.zeros(len(pw), bool)
         start = np.searchsorted(pw, ref.wire, side="left")
@@ -103,11 +149,12 @@ def hit_mismatch(prog_hits: dict, ref_hits: List[reference.Hits]) -> float:
 
 
 def compare_event(prog: dict, ref: reference.EventRef) -> Dict[str, float]:
-    """Numbers for one event; ``prog`` holds host arrays with a plane axis."""
+    """Numbers for one event; ``prog`` holds per-plane lists of host arrays
+    (``event_view``)."""
     out = {
         "grid_err": rel_l2(prog["charge_grid"], ref.grid),
         "signal_err": rel_l2(prog["signal"], ref.signal),
-        "adc_mismatch": float(np.mean(prog["adc"] != ref.adc)),
+        "adc_mismatch": mismatch_share(prog["adc"], ref.adc),
     }
     if ref.decon is not None:
         out["decon_err"] = rel_l2(prog["decon"], ref.decon)
@@ -116,16 +163,18 @@ def compare_event(prog: dict, ref: reference.EventRef) -> Dict[str, float]:
 
 
 def compare_batch(batch_out: dict, event_ids: List[int], seed: int,
-                  sizes: dict, n_depos: int, generator: str,
-                  recon: bool) -> Dict[str, float]:
+                  sizes: dict, n_depos: int, generator: str, recon: bool,
+                  source) -> Dict[str, float]:
     """Worst number over the events of one batch of program outputs
-    (``batch_out``: host arrays with a leading event axis); the events'
-    references run on threads of their own."""
+    (``batch_out``: host arrays with a leading event axis), each event
+    split by ``source.event_view`` and compared with
+    ``source.reference_event``; the events' references run on threads of
+    their own."""
 
     def one(i):
-        prog = _event_view(batch_out, i, sizes["num_planes"])
-        ref = reference_event(seed, event_ids[i], sizes, n_depos, generator,
-                              recon)
+        prog = source.event_view(batch_out, i, sizes)
+        ref = source.reference_event(seed, event_ids[i], sizes, n_depos,
+                                     generator, recon)
         return compare_event(prog, ref)
 
     with ThreadPoolExecutor(max_workers=len(event_ids)) as pool:
@@ -135,19 +184,6 @@ def compare_batch(batch_out: dict, event_ids: List[int], seed: int,
         for k, v in numbers.items():
             worst[k] = max(worst.get(k, 0.0), v) if np.isfinite(v) else np.inf
     return worst
-
-
-def _event_view(batch_out: dict, i: int, num_planes: int) -> dict:
-    """Event ``i`` of a batch, with a plane axis even for one plane."""
-
-    def take(x):
-        x = x[i]
-        return x[None] if num_planes == 1 else x
-
-    view = {k: take(v) for k, v in batch_out.items() if k != "hits"}
-    if batch_out.get("hits") is not None:
-        view["hits"] = {k: take(v) for k, v in batch_out["hits"].items()}
-    return view
 
 
 def verdict(numbers: Dict[str, float], limits: Dict[str, float]):

@@ -14,7 +14,7 @@ y: transverse position in wire pitches, z: along the wires in mm).
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -77,4 +77,20 @@ def tracks(key: jax.Array, n: int, sizes: dict) -> PhysicalDepos:
     )
 
 
-GENERATORS = {"tracks": tracks}
+def tracks_refusal(traffic: dict) -> Optional[str]:
+    """Why ``tracks`` cannot draw a traffic file's events, or None."""
+    if traffic.get("depos_per_track") != DEPOS_PER_TRACK:
+        return ("the program's stream draws tracks of "
+                f"{DEPOS_PER_TRACK} depos only")
+    return None
+
+
+class Generator(NamedTuple):
+    """A frozen generator, ``draw(key, n, sizes)``, and ``refusal(traffic)``:
+    why it cannot stand for the program's stream on that traffic, or None."""
+
+    draw: Callable[[jax.Array, int, dict], PhysicalDepos]
+    refusal: Callable[[dict], Optional[str]]
+
+
+GENERATORS = {"tracks": Generator(tracks, tracks_refusal)}

@@ -4,10 +4,18 @@ A cell is an entry of ``workloads`` in ``BENCHMARK.json``. Everything else
 is found by name:
 
     bench/configs/<config>.json   deployment: registry name, overrides, the
-                                  sizes it is run at, source, assumptions
+                                  sizes it is run at, source, assumptions,
+                                  and optionally "reference": <module>
     bench/traffic/<traffic>.json  event mix: generator, depos per event
     bench/cells/<cell>.json       stream batch, limits of the check
     bench/metrics/<metric>.py     per-layer reader (``per_layer`` entries)
+    bench/references/<module>.py  the deployment's own reference: its frozen
+                                  generators ``GENERATORS``, one event's
+                                  per-plane outputs ``reference_event`` by
+                                  its key schedule, and ``event_view``, which
+                                  splits the program's batch into per-plane
+                                  lists. A configuration that names none is
+                                  checked by ``check.py``'s own three names
 
 Set-up is everything from process start to the window: TPU
 initialisation, the response spectra, the compile of the streaming program
@@ -24,6 +32,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -65,16 +74,31 @@ def load_json(kind: str, name: str) -> dict:
     return json.loads(path.read_text())
 
 
-def load_reader(name: str):
-    """The per-layer metric module ``bench/metrics/<name>.py``."""
-    path = BENCH / "metrics" / f"{name}.py"
+def load_module(kind: str, name: str, what: str):
+    """The module ``bench/<kind>/<name>.py``, refused by ``what`` it is."""
+    path = BENCH / kind / f"{name}.py"
     if not path.is_file():
-        raise Refused(f"no reader for metric {name!r} "
-                      f"({path.relative_to(ROOT)})")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
+        raise Refused(f"no {what} {name!r} ({path.relative_to(ROOT)})")
+    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_reader(name: str):
+    """The per-layer metric module ``bench/metrics/<name>.py``."""
+    return load_module("metrics", name, "reader for metric")
+
+
+def load_reference(config: dict):
+    """The reference a configuration is checked against: the module
+    ``bench/references/<module>.py`` its ``"reference"`` names, else the
+    default in ``check.py``."""
+    if "reference" not in config:
+        from bench import check
+
+        return check
+    return load_module("references", config["reference"], "reference module")
 
 
 @dataclasses.dataclass
@@ -89,6 +113,11 @@ class Cell:
     #: batches a traced window holds: the profiler records every op of the
     #: program's loops, so a traced window is kept short
     trace_batches: int
+
+    @functools.cached_property
+    def reference(self):
+        """The module the check compares through (``load_reference``)."""
+        return load_reference(self.config)
 
     @property
     def recon(self) -> bool:
@@ -124,13 +153,12 @@ def build_config(cell: Cell):
     """The program's configuration for a cell, held to the file's sizes."""
     from repro.config import apply_overrides, get_config
 
-    from bench import depogen
-
-    if cell.traffic["generator"] not in depogen.GENERATORS:
+    gen = cell.reference.GENERATORS.get(cell.traffic["generator"])
+    if gen is None:
         raise Refused(f"unknown generator {cell.traffic['generator']!r}")
-    if cell.traffic.get("depos_per_track") != depogen.DEPOS_PER_TRACK:
-        raise Refused("the program's stream draws tracks of "
-                      f"{depogen.DEPOS_PER_TRACK} depos only")
+    reason = gen.refusal(cell.traffic)
+    if reason is not None:
+        raise Refused(reason)
     if cell.sizes["rng_strategy"] != "counter":
         raise Refused("the reference draws the 'counter' fluctuation only")
     cfg = get_config(cell.config["registry"],
@@ -238,17 +266,20 @@ class Session:
         log(f"warm-up {self.clock() - t0:.3f} s, seconds per batch {walls}")
 
     def _specs(self):
+        """The program's inputs as ``stream_simulate`` packs them: one
+        batch of empty events padded to ``num_depos``, and its keys."""
         import jax
-        import jax.numpy as jnp
 
-        from repro.core.batch import EventBatch
+        from repro.core.batch import empty_event, event_keys, pack_events
 
         cfg, e = self.cfg, self.cell.batch_events
-        lead = (e,) if cfg.num_planes == 1 else (e, cfg.num_planes)
-        f = jax.ShapeDtypeStruct(lead + (cfg.num_depos,), jnp.float32)
-        return (jax.ShapeDtypeStruct((e,), jax.random.key(0).dtype),
-                EventBatch(wire=f, tick=f, sigma_w=f, sigma_t=f, charge=f,
-                           n_depos=jax.ShapeDtypeStruct((e,), jnp.int32)))
+
+        def inputs():
+            rows = [empty_event(planes=cfg.num_planes)] * e
+            return (event_keys(jax.random.key(0), range(e)),
+                    pack_events(rows, pad_to=cfg.num_depos))
+
+        return jax.eval_shape(inputs)
 
     def _stream(self, seed: int, n_batches: int, sample: Optional[int]):
         """One ``stream_simulate`` call; returns (stats, wall_s, kept)."""
@@ -334,7 +365,8 @@ class Session:
         return check.compare_batch(
             win.kept, ids, win.seed, self.cell.sizes,
             self.cell.traffic["depos_per_event"],
-            self.cell.traffic["generator"], self.cell.recon)
+            self.cell.traffic["generator"], self.cell.recon,
+            self.cell.reference)
 
 
 def trace_summary(rec, readers: dict) -> dict:

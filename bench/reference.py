@@ -6,7 +6,9 @@ table (detector response, noise spectrum, inverse filter) is built here
 from the formulas the configuration names. The only shared parts are the
 event's depos, drawn by the frozen generator in ``bench/depogen.py``, and
 the random normals, drawn by ``jax.random`` from the event key by the key
-schedule the configuration states (the ``counter`` fluctuation strategy):
+schedule the configuration states (the ``counter`` fluctuation strategy).
+Where a configuration names no reference module of its own
+(``bench/references``), that schedule is ``check.reference_event``'s:
 
     event key   fold_in(key(seed), event_id)
     kf, kn      split(event key)                 charge grid, noise
@@ -18,13 +20,15 @@ Stages: drift, charge grid (bin-integrated Gaussian patches, binomial
 fluctuation by its normal approximation, scatter-add), convolution with the
 field x electronics response, frequency-shaped noise, digitization, and for
 recon configurations the Wiener deconvolution and the threshold hit finder.
+Each plane runs the chain on its own (``plane_chain``), at its own wire
+count, so a deployment's module can reuse it for planes that differ.
 """
 from __future__ import annotations
 
 import math
 import os
 from concurrent.futures import ThreadPoolExecutor
-from typing import NamedTuple, Optional
+from typing import List, NamedTuple, Optional
 
 import numpy as np
 import scipy.fft
@@ -35,12 +39,13 @@ WORKERS = os.cpu_count() or 1
 
 
 class EventRef(NamedTuple):
-    """Reference outputs of one event; leaves carry a leading plane axis."""
+    """Reference outputs of one event: lists with one array per plane, each
+    (wires of that plane, T), so planes may differ in wire count."""
 
-    grid: np.ndarray  # (P, W, T) charge after fluctuation, electrons
-    signal: np.ndarray  # (P, W, T) after convolution and noise, electrons
-    adc: np.ndarray  # (P, W, T) int16 counts
-    decon: Optional[np.ndarray] = None  # (P, W, T) recon only
+    grid: List[np.ndarray]  # charge after fluctuation, electrons
+    signal: List[np.ndarray]  # after convolution and noise, electrons
+    adc: List[np.ndarray]  # int16 counts
+    decon: Optional[List[np.ndarray]] = None  # recon only
     hits: Optional[list] = None  # per plane: Hits
 
 
@@ -130,11 +135,12 @@ def _axis_weights(center, sigma, origin, npix: int):
     return np.maximum(0.5 * (cdf[:, 1:] - cdf[:, :-1]), 0.0)
 
 
-def charge_grid(depos: dict, normals: np.ndarray, sizes: dict,
-                chunk: int = 4096) -> np.ndarray:
-    """Bin-integrated Gaussian patches, fluctuated, summed into the grid
-    (in chunks of depos, so each chunk's arrays stay in cache)."""
-    nw, nt = sizes["num_wires"], sizes["num_ticks"]
+def charge_grid(depos: dict, normals: np.ndarray, num_wires: int,
+                sizes: dict, chunk: int = 4096) -> np.ndarray:
+    """Bin-integrated Gaussian patches, fluctuated, summed into a grid of
+    ``num_wires`` wires (in chunks of depos, so each chunk's arrays stay in
+    cache)."""
+    nw, nt = num_wires, sizes["num_ticks"]
     pw, pt = sizes["patch_wires"], sizes["patch_ticks"]
     w0 = np.clip(np.rint(depos["wire"]).astype(np.int64) - pw // 2, 0, nw - pw)
     t0 = np.clip(np.rint(depos["tick"]).astype(np.int64) - pt // 2, 0, nt - pt)
@@ -297,15 +303,14 @@ def find_hits(decon: np.ndarray, sizes: dict) -> Hits:
 # ---------------------------------------------------------------------------
 
 
-def simulate_plane(phys: dict, p: int, draws, sizes: dict, recon: bool):
-    """One plane's chain; ``draws(p)`` gives its fluctuation normals and its
-    (re, im) noise normals."""
-    kind, angle, pitch = planes(sizes)[p]
-    normals, noise_draws = draws(p)
-    wire = project(phys["y"], phys["z"], angle, pitch, sizes)
-    depos = drift(dict(phys, wire=wire), pitch, sizes)
-    grid = charge_grid(depos, normals, sizes)
-    del normals, depos
+def plane_chain(depos: dict, kind: str, num_wires: int, normals,
+                noise_draws, sizes: dict, recon: bool) -> dict:
+    """One plane's chain from its drifted depos (``drift``) to its outputs
+    ("grid", "signal", "adc", and in recon "decon", "hits"), on a plane of
+    ``num_wires`` wires with a ``kind`` response. ``normals``: its
+    fluctuation normals, (depos, patch_wires, patch_ticks); ``noise_draws``:
+    its (re, im) noise normals, each (num_wires, ticks // 2 + 1)."""
+    grid = charge_grid(depos, normals, num_wires, sizes)
     kernel = response_kernel(kind, sizes)
     signal = convolve(grid, kernel) + noise(noise_draws, sizes) / max(
         sizes["adc_per_electron"], 1e-30)
@@ -317,9 +322,34 @@ def simulate_plane(phys: dict, p: int, draws, sizes: dict, recon: bool):
     return out
 
 
+def simulate_plane(phys: dict, p: int, draws, sizes: dict, recon: bool):
+    """Plane p of the default geometry (``planes``, ``project``), every
+    plane ``num_wires`` wires; ``draws(p)`` gives its fluctuation normals
+    and its (re, im) noise normals."""
+    kind, angle, pitch = planes(sizes)[p]
+    normals, noise_draws = draws(p)
+    wire = project(phys["y"], phys["z"], angle, pitch, sizes)
+    depos = drift(dict(phys, wire=wire), pitch, sizes)
+    return plane_chain(depos, kind, sizes["num_wires"], normals, noise_draws,
+                       sizes, recon)
+
+
+def event_ref(outs: list, recon: bool) -> EventRef:
+    """The per-plane lists of ``plane_chain`` outputs, in plane order."""
+
+    def per_plane(key):
+        return [o[key] for o in outs]
+
+    return EventRef(grid=per_plane("grid"), signal=per_plane("signal"),
+                    adc=per_plane("adc"),
+                    decon=per_plane("decon") if recon else None,
+                    hits=per_plane("hits") if recon else None)
+
+
 def simulate_event(phys: dict, draws, sizes: dict, recon: bool) -> EventRef:
-    """The whole chain for one event, its planes on threads of their own
-    (NumPy and SciPy release the interpreter lock in the array work).
+    """The whole chain for one event of the default geometry, its planes on
+    threads of their own (NumPy and SciPy release the interpreter lock in
+    the array work).
 
     ``phys``: float32 arrays x, y, z, t, q of the physical depos;
     ``draws(p)``: plane p's normals, as ``simulate_plane`` takes them.
@@ -328,10 +358,4 @@ def simulate_event(phys: dict, draws, sizes: dict, recon: bool) -> EventRef:
     with ThreadPoolExecutor(max_workers=n) as pool:
         outs = list(pool.map(
             lambda p: simulate_plane(phys, p, draws, sizes, recon), range(n)))
-
-    def stack(key):
-        return np.stack([o[key] for o in outs])
-
-    return EventRef(grid=stack("grid"), signal=stack("signal"),
-                    adc=stack("adc"), decon=stack("decon") if recon else None,
-                    hits=[o["hits"] for o in outs] if recon else None)
+    return event_ref(outs, recon)

@@ -40,7 +40,6 @@ STAGE = {"charge_grid_ms_per_event": 300, "convolve_ms_per_event": 50,
 EXISTING = {"device_idle_share": 19.749984216175264,
             "host_prep_ms_per_event": 168.3155,
             "device_ms_per_event": 270.10450000000003,
-            "scatter_ms_per_event": 49.70825,
             "fft_ms_per_event": 215.81150000000002}
 
 
@@ -105,7 +104,6 @@ def test_loading_every_reader_leaves_the_trace_loader_as_it_is():
 RECORDED = {"device_idle_share": 8.205236193932874,
             "host_prep_ms_per_event": 434.01598275,
             "device_ms_per_event": 791.2375957500001,
-            "scatter_ms_per_event": 504.00333650000005,
             "fft_ms_per_event": 263.14590875,
             "charge_grid_ms_per_event": 517.4083412499999,
             "convolve_ms_per_event": 138.20904975,

@@ -42,8 +42,6 @@ def test_busy_union_and_idle_share():
 
 def test_sums_by_op_kind():
     rec = hand_record()
-    assert reader("scatter_ms_per_event").read(rec) == pytest.approx(
-        1e3 * 10e-9 / 2)
     assert reader("fft_ms_per_event").read(rec) == pytest.approx(
         1e3 * 11e-9 / 2)
 
@@ -69,7 +67,6 @@ def test_breakdown_labels_each_gap_by_the_host_region_it_fell_in():
 def test_a_reader_with_nothing_to_read_returns_nothing():
     rec = tracereduce.Record(window=(0, 100), ops={0: [("a", 0, 50)]},
                              spans=SPANS, kinds={"a": ""}, events=2)
-    assert reader("scatter_ms_per_event").read(rec) is None
     assert reader("fft_ms_per_event").read(rec) is None
     empty = tracereduce.Record(window=(0, 100), ops={}, spans=[],
                                kinds={}, events=0)
@@ -136,5 +133,4 @@ def test_recorded_trace_reduces_as_by_a_sweep():
     assert len(prep) == sum(1 for n, _, _ in rec.spans
                             if n == "bench.dispatch")
     assert all(s <= e for s, e in prep)
-    assert reader("scatter_ms_per_event").read(rec) > 0
     assert reader("fft_ms_per_event").read(rec) > 0
