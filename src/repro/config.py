@@ -235,6 +235,12 @@ class LArTPCConfig:
     # per-plane field-response type: "induction" (bipolar) | "collection"
     # (unipolar) — selects the plane's ``make_response`` kernel
     plane_types: Tuple[str, ...] = ("induction", "induction", "collection")
+    # per-plane wire count; () means ``num_wires`` on every plane, each
+    # output leaf then carrying a plane axis (P, num_wires, num_ticks).
+    # Given, the planes stack along ONE wire axis in plane order, each at
+    # its own count: (sum of plane_wires, num_ticks), hits (P, max_hits),
+    # over the face those wires cover (``face_extent_mm``)
+    plane_wires: Tuple[int, ...] = ()
     # how the plane axis is dispatched when ``num_planes > 1`` (ISSUE 9):
     #   loop    : the original static Python loop — P charge-grid/convolve/
     #             noise programs and (distributed) P collectives per step
@@ -290,6 +296,7 @@ class PlaneSpec(NamedTuple):
     kind: str          # "induction" | "collection"
     angle_deg: float   # wire angle from vertical, degrees
     pitch_mm: float    # wire pitch of this plane
+    num_wires: int     # wires of this plane
 
 
 def plane_specs(cfg: "LArTPCConfig") -> Tuple[PlaneSpec, ...]:
@@ -300,16 +307,20 @@ def plane_specs(cfg: "LArTPCConfig") -> Tuple[PlaneSpec, ...]:
     angle 0, pitch ``wire_pitch_mm``) with the bipolar induction response —
     the exact pre-multi-plane behavior, so the plane tuples are not
     consulted. ``num_planes > 1`` reads the first ``num_planes`` entries of
-    ``plane_angles_deg`` / ``plane_pitches_mm`` / ``plane_types``.
+    ``plane_angles_deg`` / ``plane_pitches_mm`` / ``plane_types`` /
+    ``plane_wires``.
     """
     if cfg.num_planes < 1:
         raise ValueError(f"num_planes must be >= 1, got {cfg.num_planes}")
     if cfg.num_planes == 1:
-        return (PlaneSpec(0, "induction", 0.0, cfg.wire_pitch_mm),)
+        return (PlaneSpec(0, "induction", 0.0, cfg.wire_pitch_mm,
+                          cfg.num_wires),)
     pitches = cfg.plane_pitches_mm or (cfg.wire_pitch_mm,) * cfg.num_planes
+    wires = cfg.plane_wires or (cfg.num_wires,) * cfg.num_planes
     for name, tup in (("plane_angles_deg", cfg.plane_angles_deg),
                       ("plane_pitches_mm", pitches),
-                      ("plane_types", cfg.plane_types)):
+                      ("plane_types", cfg.plane_types),
+                      ("plane_wires", wires)):
         if len(tup) < cfg.num_planes:
             raise ValueError(
                 f"{name} has {len(tup)} entries < num_planes={cfg.num_planes}")
@@ -318,8 +329,51 @@ def plane_specs(cfg: "LArTPCConfig") -> Tuple[PlaneSpec, ...]:
             raise ValueError(f"unknown plane type {kind!r}; expected "
                              "'induction' or 'collection'")
     return tuple(
-        PlaneSpec(p, cfg.plane_types[p], cfg.plane_angles_deg[p], pitches[p])
+        PlaneSpec(p, cfg.plane_types[p], cfg.plane_angles_deg[p], pitches[p],
+                  wires[p])
         for p in range(cfg.num_planes))
+
+
+def concat_planes(cfg: "LArTPCConfig") -> bool:
+    """True when the planes stack along one wire axis: a multi-plane
+    readout given per-plane wire counts (``plane_wires``)."""
+    return cfg.num_planes > 1 and bool(cfg.plane_wires)
+
+
+def face_extent_mm(cfg: "LArTPCConfig") -> Tuple[float, float]:
+    """(y, z) extent in mm of the face the generator draws over and the
+    planes are centred on; y runs along the 0-degree plane's pitch, z along
+    its wires.
+
+    Planes of their own wire counts (``concat_planes``) read the face their
+    wires cover exactly: y spans the 0-degree plane's wires, and z is the
+    height at which every angled plane's wires span the face's projection,
+    (wires pitch - y |cos a|) / |sin a| (the least over the angled planes).
+    Otherwise it is the seed box of ``num_wires`` wires, y in
+    [0, (num_wires - 1) pitch], z in [0, num_wires pitch].
+    """
+    import math
+
+    if not concat_planes(cfg):
+        return ((cfg.num_wires - 1.0) * cfg.wire_pitch_mm,
+                cfg.num_wires * cfg.wire_pitch_mm)
+    specs = plane_specs(cfg)
+    straight = [s for s in specs if s.angle_deg == 0.0]
+    angled = [s for s in specs if s.angle_deg != 0.0]
+    if not straight or not angled:
+        raise ValueError(
+            "planes of their own wire counts (plane_wires) need a 0-degree "
+            "plane, whose wires span the face, and an angled one, whose "
+            f"wires fix its height; got angles {[s.angle_deg for s in specs]}")
+    y = straight[0].num_wires * straight[0].pitch_mm
+    z = min((s.num_wires * s.pitch_mm
+             - y * abs(math.cos(math.radians(s.angle_deg))))
+            / abs(math.sin(math.radians(s.angle_deg))) for s in angled)
+    if z <= 0.0:
+        raise ValueError(
+            f"the angled planes' wires do not span the {y} mm the 0-degree "
+            "plane's wires cover (plane_wires)")
+    return y, z
 
 
 # ---------------------------------------------------------------------------
@@ -442,6 +496,10 @@ def _apply_one(cfg, parts, value):
                 value = float(value)
             elif typ in ("bool", bool):
                 value = value.lower() in ("1", "true", "yes")
+        elif isinstance(value, list):
+            # a JSON list: the frozen config hashes, so a list field would
+            # not (jit keys on the config)
+            value = tuple(value)
         return replace(cfg, **{parts[0]: value})
     sub = getattr(cfg, parts[0])
     return replace(cfg, **{parts[0]: _apply_one(sub, parts[1:], value)})

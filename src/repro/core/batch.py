@@ -258,7 +258,8 @@ def simulate_events(keys: jax.Array, batch: EventBatch, resp: DetectorResponse,
                     add_noise: bool = True, recon: bool = False,
                     graph: Optional[SimGraph] = None) -> SimOutput:
     """The canonical SimGraph for all E events in one program: vmap over the
-    event axis (the batched executor of ``repro.core.stages``).
+    event axis (the batched executor of ``repro.core.stages``,
+    ``SimGraph.run_events``, which draws the noise one event at a time).
 
     keys : (E,) PRNG keys (one per event — events stay independent).
     Returns a SimOutput whose leaves carry a leading event axis:
@@ -276,7 +277,7 @@ def simulate_events(keys: jax.Array, batch: EventBatch, resp: DetectorResponse,
 
     depos = jax.tree.map(lambda x: logical(x, ev_names(x)), depos)
     keys = logical(keys, ("events",))
-    out = jax.vmap(graph.run)(keys, depos)
+    out = graph.run_events(keys, depos)
     # tree.map (not per-field) so nested recon leaves (the HitSet) get the
     # event-axis constraint too and absent (None) fields pass through
     return jax.tree.map(lambda x: logical(x, ev_names(x)), out)

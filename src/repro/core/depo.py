@@ -53,14 +53,18 @@ def generate_physical_depos(key: jax.Array, cfg: LArTPCConfig,
     diffusion, lifetime attenuation, recombination — is the drift stage's
     job, not the generator's.
     """
+    from repro.config import face_extent_mm
     from repro.core.drift import PhysicalDepoSet
 
     n = n or cfg.num_depos
     n_tracks = max(1, n // 512)  # ~512 depos per track segment
     k1, k2, k3, k4, k5 = jax.random.split(key, 5)
+    # the face: y in wire pitches (num_wires - 1 for the seed box), z in mm
+    y_max, z_extent = face_extent_mm(cfg)
+    y_max = y_max / cfg.wire_pitch_mm
 
     # track entry points and direction (in wire/tick coordinates)
-    entry_w = jax.random.uniform(k1, (n_tracks,), minval=0.0, maxval=cfg.num_wires - 1.0)
+    entry_w = jax.random.uniform(k1, (n_tracks,), minval=0.0, maxval=y_max)
     entry_t = jax.random.uniform(k2, (n_tracks,), minval=0.0, maxval=cfg.num_ticks - 1.0)
     theta = jax.random.uniform(k3, (n_tracks,), minval=-1.2, maxval=1.2)
 
@@ -71,15 +75,14 @@ def generate_physical_depos(key: jax.Array, cfg: LArTPCConfig,
     wires = wires.reshape(-1)[:n]
     ticks = ticks.reshape(-1)[:n]
     # keep everything inside the active volume (reflect)
-    wires = jnp.clip(jnp.abs(wires), 0, cfg.num_wires - 1)
+    wires = jnp.clip(jnp.abs(wires), 0, y_max)
     ticks = jnp.clip(jnp.abs(ticks), 0, cfg.num_ticks - 1)
 
-    # along-wire position z [mm]: tracks slope through a square-ish
-    # transverse volume. Unused by the identity single-plane readout (its
-    # projection never reads z, so these draws don't perturb it) but gives
-    # the rotated U/V planes of a multi-plane config real geometry.
+    # along-wire position z [mm]: tracks slope through the face. Unused by
+    # the identity single-plane readout (its projection never reads z, so
+    # these draws don't perturb it) but gives the rotated U/V planes of a
+    # multi-plane config real geometry.
     k5a, k5b = jax.random.split(k5)
-    z_extent = cfg.num_wires * cfg.wire_pitch_mm
     entry_z = jax.random.uniform(k5a, (n_tracks,), minval=0.0,
                                  maxval=z_extent)
     dz = jax.random.uniform(k5b, (n_tracks,), minval=-2.0, maxval=2.0)

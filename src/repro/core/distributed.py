@@ -94,8 +94,15 @@ def make_distributed_sim(mesh: Mesh, cfg: LArTPCConfig, resp,
                      its own wire coordinate, so one host-side wire binning
                      cannot serve them all.
     """
-    from repro.config import plane_specs
+    from repro.config import concat_planes, plane_specs
 
+    if concat_planes(cfg):
+        raise ValueError(
+            "make_distributed_sim shards one (num_planes, W_pad, T) grid, "
+            "every plane num_wires wide; this config gives its planes their "
+            f"own wire counts (plane_wires={tuple(cfg.plane_wires)}), which "
+            "the wire-sharded executor does not support — run it on one "
+            "device (make_batched_sim_fn / stream_simulate)")
     axes = tuple(axes)
     specs = plane_specs(cfg)
     multi = cfg.num_planes > 1

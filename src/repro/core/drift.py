@@ -176,30 +176,33 @@ def project_to_plane(pdepos: PhysicalDepoSet, spec, cfg: LArTPCConfig
 
     with ``cw = cos(angle) * wire_pitch_mm / pitch_p`` and
     ``cz = sin(angle) / pitch_p`` precomputed as Python floats. ``off_p``
-    centers the plane on the detector: wire (num_wires-1)/2 sits at the
-    projected midpoint of the transverse box (y_mm in
-    [0, (num_wires-1)*wire_pitch_mm], z in [0, num_wires*wire_pitch_mm] —
-    the generator's volume), the convention a real readout uses for its
-    wire numbering. Without it a rotated plane's coordinates run
-    one-sided (e.g. -60 deg projects z negative-ward only) and a large
-    fraction of the event would fall off the low-wire edge; centering
-    loses only the symmetric corner overhangs a ±60 deg plane cannot
-    cover with ``num_wires`` wires. The angle-0 reference-pitch plane has
-    ``cw == 1.0, cz == 0.0, off == 0.0`` and skips the arithmetic
-    entirely — bit-identical to the seed single-plane path (no lossy unit
-    round trip; see the module docstring).
+    centers the plane on the detector face (``face_extent_mm``: y_mm in
+    [0, y extent], z in [0, z extent], the generator's volume): the
+    plane's middle wire, (spec.num_wires-1)/2, sits at the projected
+    midpoint of the face, the convention a real readout uses for its wire
+    numbering. Without it a rotated plane's coordinates run one-sided
+    (e.g. -60 deg projects z negative-ward only) and a large fraction of
+    the event would fall off the low-wire edge. Where the face is the
+    seed's square box of ``num_wires`` wires, centering loses the
+    symmetric corner overhangs a ±60 deg plane cannot cover with
+    ``num_wires`` wires; the face planes of their own wire counts cover
+    (``cfg.plane_wires``) has none. The angle-0 reference-pitch plane of the
+    seed box has ``cw == 1.0, cz == 0.0, off == 0.0`` and skips the
+    arithmetic entirely — bit-identical to the seed single-plane path (no
+    lossy unit round trip; see the module docstring).
     """
     import math
+
+    from repro.config import face_extent_mm
 
     rad = math.radians(spec.angle_deg)
     cos_, sin_ = math.cos(rad), math.sin(rad)
     cw = cos_ * cfg.wire_pitch_mm / spec.pitch_mm
     cz = sin_ / spec.pitch_mm
-    y_max = (cfg.num_wires - 1.0) * cfg.wire_pitch_mm
-    z_max = cfg.num_wires * cfg.wire_pitch_mm
+    y_max, z_max = face_extent_mm(cfg)
     lo = min(0.0, y_max * cos_) + min(0.0, z_max * sin_)
     hi = max(0.0, y_max * cos_) + max(0.0, z_max * sin_)
-    off = (cfg.num_wires - 1.0) / 2.0 - (lo + hi) / (2.0 * spec.pitch_mm)
+    off = (spec.num_wires - 1.0) / 2.0 - (lo + hi) / (2.0 * spec.pitch_mm)
     if abs(off) < 1e-6:
         off = 0.0
     if cw == 1.0 and cz == 0.0 and off == 0.0:

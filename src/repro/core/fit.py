@@ -313,7 +313,7 @@ def make_fit_targets(cfg: LArTPCConfig, key: jax.Array, num_events: int = 2,
     graph = build_sim_graph(cfg, None, add_noise=add_noise, recon=recon)
     if recon:
         graph = _drop_stage(graph, "hit_find")
-    out: SimOutput = jax.jit(jax.vmap(graph.run))(keys, batch.physical_set())
+    out: SimOutput = jax.jit(graph.run_events)(keys, batch.physical_set())
     return FitTargets(batch=batch, keys=keys, adc=out.adc, decon=out.decon)
 
 
@@ -325,8 +325,9 @@ def make_fit_loss(cfg: LArTPCConfig, spec: FitSpec, targets: FitTargets,
     The loss rebuilds the config — and therefore the detector response, the
     noise spectrum, and every stage closure — inside the traced function
     from ``spec.apply(fit_config(cfg), theta)``, runs the differentiable
-    graph under ``vmap`` over the target events (same per-event keys as the
-    target run), and returns
+    graph over the target events with the batched executor
+    (``SimGraph.run_events``; the same per-event keys as the target run),
+    and returns
 
         mean((adc - target_adc)^2)
           [+ decon_weight * mean((decon - target_decon)^2)]
@@ -352,7 +353,7 @@ def make_fit_loss(cfg: LArTPCConfig, spec: FitSpec, targets: FitTargets,
                                 recon=use_decon)
         if use_decon:
             graph = _drop_stage(graph, "hit_find")
-        out = jax.vmap(graph.run)(targets.keys, depos)
+        out = graph.run_events(targets.keys, depos)
         val = jnp.mean((out.adc - target_adc) ** 2)
         if use_decon:
             val = val + decon_weight * jnp.mean((out.decon - targets.decon) ** 2)

@@ -21,13 +21,14 @@ fig3 host loop. ``make_sim_fn`` resolves any ``"auto"`` strategy fields
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.config import LArTPCConfig
+from repro.config import LArTPCConfig, concat_planes
 from repro.core import fluctuate as fl
 from repro.core.depo import DepoSet, depo_patch_origin
 from repro.core.fft_conv import digitize, fft_convolve
@@ -61,13 +62,31 @@ def _fluctuate(key, patches, charge, cfg: LArTPCConfig, pool=None):
 # ---------------------------------------------------------------------------
 
 
+def charge_patches(key: jax.Array, depos: DepoSet, cfg: LArTPCConfig,
+                   pool: Optional[jax.Array] = None):
+    """The unfused chain up to its scatter: fluctuated patches (N, pw, pt)
+    in ``cfg.patch_dtype`` and their (N,) wire and tick origins."""
+    patches, w0, t0 = rasterize(depos, cfg)
+    return _fluctuate(key, patches, depos.charge, cfg, pool), w0, t0
+
+
+def unfused_cfg(strategy: str, cfg: LArTPCConfig) -> LArTPCConfig:
+    """``cfg`` as the unfused charge-grid ``strategy`` runs its chain
+    (``charge_patches`` and ``scatter_add``): ``unfused_bf16`` in
+    bfloat16 patches, ``unfused`` as configured."""
+    if strategy == "unfused_bf16":
+        return dataclasses.replace(cfg, patch_dtype="bfloat16")
+    if strategy != "unfused":
+        raise ValueError(f"{strategy!r} is not an unfused charge_grid "
+                         "strategy ('unfused', 'unfused_bf16')")
+    return cfg
+
+
 @register_strategy("charge_grid", "unfused",
                    note="rasterize -> fluctuate -> scatter_add")
 def charge_grid_unfused(key: jax.Array, depos: DepoSet, cfg: LArTPCConfig,
                         pool: Optional[jax.Array] = None) -> jax.Array:
-    patches, w0, t0 = rasterize(depos, cfg)
-    patches = _fluctuate(key, patches, depos.charge, cfg, pool)
-    return scatter_add(patches, w0, t0, cfg)
+    return scatter_add(*charge_patches(key, depos, cfg, pool), cfg)
 
 
 @register_strategy("charge_grid", "unfused_bf16",
@@ -75,10 +94,8 @@ def charge_grid_unfused(key: jax.Array, depos: DepoSet, cfg: LArTPCConfig,
 def charge_grid_unfused_bf16(key: jax.Array, depos: DepoSet,
                              cfg: LArTPCConfig,
                              pool: Optional[jax.Array] = None) -> jax.Array:
-    import dataclasses
-
-    return charge_grid_unfused(
-        key, depos, dataclasses.replace(cfg, patch_dtype="bfloat16"), pool)
+    return charge_grid_unfused(key, depos, unfused_cfg("unfused_bf16", cfg),
+                               pool)
 
 
 #: Mosaic (JAX 0.9.0) refuses every fused kernel at lowering:
@@ -99,8 +116,8 @@ def _fused_viable(ctx) -> bool:
     cfg = ctx.cfg
     if cfg is None or (cfg.fluctuate and cfg.rng_strategy in ("pool", "relaxed")):
         return False
-    if ctx.backend == "tpu":
-        return False  # FUSED_TPU_REFUSAL
+    if ctx.backend == "tpu" or concat_planes(cfg):
+        return False  # FUSED_TPU_REFUSAL; planes of their own wire counts
     cells = ctx.shape.get("num_wires", 0) * ctx.shape.get("num_ticks", 0)
     return cells <= (1 << 21)
 
@@ -148,7 +165,7 @@ def _fused_mp_viable(ctx) -> bool:
     # kernels (in-kernel counter RNG), and off-TPU the interpreter budget
     # scales with the number of planes it rasterizes per launch
     cfg = ctx.cfg
-    if cfg is None or cfg.num_planes < 2:
+    if cfg is None or cfg.num_planes < 2 or concat_planes(cfg):
         return False
     if cfg.fluctuate and cfg.rng_strategy in ("pool", "relaxed"):
         return False
@@ -217,9 +234,11 @@ def _mp_xla_viable(ctx) -> bool:
     # plane-flattened XLA chain: needs a plane axis to amortize, and its
     # fluctuation randomness is the fused kernels' counter hash, which (like
     # them) cannot reproduce the pre-computed pool/relaxed streams. No cell
-    # cap — plain XLA scales to production grids on every backend.
+    # cap — plain XLA scales to production grids on every backend. Its
+    # grid is (P, W, T): planes of their own wire counts take the unfused
+    # chain's one scatter over the concatenated wire axis instead.
     cfg = ctx.cfg
-    if cfg is None or cfg.num_planes < 2:
+    if cfg is None or cfg.num_planes < 2 or concat_planes(cfg):
         return False
     return not (cfg.fluctuate and cfg.rng_strategy in ("pool", "relaxed"))
 
@@ -242,8 +261,6 @@ def charge_grid_multiplane_xla(key: jax.Array, depos: DepoSet,
     different bit stream than ``unfused``, so it carries its own pinned
     goldens.
     """
-    import dataclasses
-
     del pool  # counter-hash RNG; the pool strategy is rejected above
     _require_plane_axis(depos, cfg)
     n_planes, n = depos.wire.shape[0], depos.wire.shape[-1]

@@ -68,7 +68,10 @@ def next_fast_len(n: int) -> int:
     return best
 
 
-def make_response(cfg: LArTPCConfig, plane: str = "induction") -> DetectorResponse:
+def make_response(cfg: LArTPCConfig, plane: str = "induction",
+                  num_wires: int | None = None) -> DetectorResponse:
+    """The response of a ``plane``-kind readout of ``num_wires`` wires
+    (default ``cfg.num_wires``), transformed at its padded grid shape."""
     rw, rt = cfg.response_wires, cfg.response_ticks
     t_us = jnp.arange(rt, dtype=jnp.float32) * cfg.tick_us
     time_resp = _field_time(t_us, plane)
@@ -91,7 +94,7 @@ def make_response(cfg: LArTPCConfig, plane: str = "induction") -> DetectorRespon
     if isinstance(gain, jax.Array) or gain != 1.0:
         kernel = kernel * gain
 
-    w_pad = next_fast_len(cfg.num_wires + rw - 1)
+    w_pad = next_fast_len((num_wires or cfg.num_wires) + rw - 1)
     t_pad = next_fast_len(cfg.num_ticks + rt - 1)
     kpad = jnp.zeros((w_pad, t_pad), jnp.float32)
     kpad = kpad.at[:rw, :rt].set(kernel)
@@ -104,10 +107,12 @@ def make_response(cfg: LArTPCConfig, plane: str = "induction") -> DetectorRespon
 
 def make_plane_responses(cfg: LArTPCConfig):
     """One ``DetectorResponse`` per readout plane of ``cfg``, in plane order
-    (bipolar for induction planes, unipolar for the collection plane)."""
+    (bipolar for induction planes, unipolar for the collection plane), each
+    padded for its plane's wire count."""
     from repro.config import plane_specs
 
-    return tuple(make_response(cfg, plane=s.kind) for s in plane_specs(cfg))
+    return tuple(make_response(cfg, plane=s.kind, num_wires=s.num_wires)
+                 for s in plane_specs(cfg))
 
 
 def make_distributed_response(cfg: LArTPCConfig, w_pad: int,

@@ -17,7 +17,9 @@ object instead of code duplicated across entry points:
 All four production entry points execute the same graph object:
 
   make_sim_fn           : jit(graph.run)                       (single event)
-  make_batched_sim_fn   : jit(vmap(graph.run))                 (event batch)
+  make_batched_sim_fn   : jit(graph.run_events)                (event batch:
+                          each stage vmapped over the events, noise
+                          drawn one event at a time)
                           (both via ``jit_executor``: the response spectra
                           are program arguments, not baked-in literals)
   make_distributed_sim  : jit(shard_map(graph.run))            (multi-device,
@@ -44,10 +46,10 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.config import LArTPCConfig, plane_specs
+from repro.config import LArTPCConfig, concat_planes, plane_specs
 from repro.core.depo import DepoSet
 from repro.core.fft_conv import digitize, fft_convolve
-from repro.core.noise import simulate_noise
+from repro.core.noise import noise_spectrum, sample_noise_rows
 from repro.core.response import DetectorResponse
 
 #: canonical stage order of the simulation chain
@@ -61,7 +63,10 @@ FULL_STAGE_ORDER = STAGE_ORDER + RECON_STAGE_ORDER
 class SimOutput(NamedTuple):
     """Simulation result. Single-plane configs (``num_planes == 1``) keep
     the seed 2-D layout; multi-plane configs carry a leading plane axis on
-    every leaf: adc (P, num_wires, num_ticks), etc.
+    every leaf: adc (P, num_wires, num_ticks), etc. — except those given
+    per-plane wire counts (``cfg.plane_wires``), whose planes stack along
+    one wire axis in plane order: adc (sum of plane_wires, num_ticks), with
+    hits still (P, max_hits).
 
     ``decon``/``hits`` are populated only by recon graphs
     (``build_sim_graph(..., recon=True)``) and stay None — an empty pytree
@@ -112,6 +117,9 @@ class Stage:
     name: str
     fn: Callable[[SimState], SimState]
     op: Optional[str] = None
+    #: under the batched executor (``SimGraph.run_events``) run one event
+    #: at a time (``lax.map``) instead of vmapped over the event axis
+    per_event: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -168,6 +176,30 @@ class SimGraph:
         """Execute the full chain for one event. jit/vmap/shard_map-able."""
         return self.output(self.run_state(self.init_state(key, depos)))
 
+    def run_events(self, keys: jax.Array, depos) -> SimOutput:
+        """Execute the chain for a batch of events (a leading event axis on
+        ``keys`` and every depo leaf): each stage vmapped over the events,
+        as ``vmap(run)`` would, except ``per_event`` stages, which map over
+        them one at a time. Per-event results equal ``run``'s."""
+        state = jax.vmap(self.init_state)(keys, depos)
+        for s in self.stages:
+            if s.per_event:
+                with jax.named_scope(s.name):
+                    state = jax.lax.map(s.fn, state)
+            else:
+                state = jax.vmap(_in_scope(s))(state)
+        return self.output(state)
+
+
+def _in_scope(stage: Stage) -> Callable[[SimState], SimState]:
+    """``stage.fn`` under the stage's named scope."""
+
+    def fn(state: SimState) -> SimState:
+        with jax.named_scope(stage.name):
+            return stage.fn(state)
+
+    return fn
+
 
 # ---------------------------------------------------------------------------
 # Stage factories — the default (single-device fig4) implementations
@@ -179,6 +211,12 @@ class SimGraph:
 # subset of plane indices (a one-plane graph of a multi-plane config); it
 # has no effect on single-plane configs, whose stages are byte-for-byte the
 # seed implementations.
+#
+# Planes of their own wire counts (``concat_planes(cfg)``) stack along one
+# wire axis instead, plane p's rows at ``plane_rows`` — the charge grid is
+# ONE scatter over every plane's patches; convolve, noise, deconvolve and
+# hit_find run per plane (their transform shapes differ), each plane's ops
+# under ``jax.named_scope(<plane kind>)`` inside the stage's scope.
 # ---------------------------------------------------------------------------
 
 
@@ -223,6 +261,26 @@ def _selected_specs(cfg: LArTPCConfig, planes: Optional[Tuple[int, ...]]):
     return tuple(specs[p] for p in planes)
 
 
+def plane_rows(specs) -> Tuple[Tuple[int, int], ...]:
+    """(first row, wire count) of each plane on the concatenated wire axis,
+    in the order of ``specs``."""
+    rows, start = [], 0
+    for spec in specs:
+        rows.append((start, spec.num_wires))
+        start += spec.num_wires
+    return tuple(rows)
+
+
+def _per_plane(specs, grid: jax.Array, fn) -> jax.Array:
+    """``fn(i, spec, rows)`` on each plane's rows of a concatenated (sum W,
+    T) array, under the plane kind's scope, concatenated back."""
+    parts = []
+    for i, (spec, (start, n)) in enumerate(zip(specs, plane_rows(specs))):
+        with jax.named_scope(spec.kind):
+            parts.append(fn(i, spec, grid[start:start + n]))
+    return jnp.concatenate(parts)
+
+
 def _as_plane_responses(cfg: LArTPCConfig, resp,
                         planes: Optional[Tuple[int, ...]]):
     """Normalize ``resp`` to one response per selected plane.
@@ -236,7 +294,8 @@ def _as_plane_responses(cfg: LArTPCConfig, resp,
 
     specs = _selected_specs(cfg, planes)
     if resp is None:
-        return tuple(make_response(cfg, plane=s.kind) for s in specs)
+        return tuple(make_response(cfg, plane=s.kind, num_wires=s.num_wires)
+                     for s in specs)
     if isinstance(resp, DetectorResponse):
         if len(specs) != 1:
             raise ValueError(
@@ -321,6 +380,7 @@ def charge_grid_stage(cfg: LArTPCConfig,
     matching its fixed-pool design across events.)"""
     specs = _selected_specs(cfg, planes)
     multi = cfg.num_planes > 1
+    concat = concat_planes(cfg)
     stacked = multi and resolve_plane_batching(cfg) == "stacked"
 
     def loop_fn(state: SimState) -> SimState:
@@ -331,10 +391,43 @@ def charge_grid_stage(cfg: LArTPCConfig,
             grids.append(compute_charge_grid(kf, depos_p, cfg, pool=pool))
         return state._replace(grid=jnp.stack(grids))
 
+    def concat_fn(state: SimState) -> SimState:
+        from repro.core.pipeline import charge_patches, unfused_cfg
+        from repro.core.scatter import scatter_add
+        from repro.tune import autotune
+
+        strategy = cfg.charge_grid_strategy
+        if strategy == "auto":
+            strategy = autotune.resolve("charge_grid", cfg).strategy
+        if strategy not in PLANE_VMAP_CHARGE_GRID:
+            raise ValueError(
+                f"charge_grid strategy {strategy!r} fills a (P, W, T) grid; "
+                "planes of their own wire counts (plane_wires) take "
+                f"{PLANE_VMAP_CHARGE_GRID}")
+        scfg = unfused_cfg(strategy, cfg)
+        patches, w0s, t0s = [], [], []
+        for i, (spec, (start, n)) in enumerate(zip(specs, plane_rows(specs))):
+            p, w0, t0 = charge_patches(
+                jax.random.fold_in(state.kf, spec.index),
+                jax.tree.map(lambda x, i=i: x[i], state.depos),
+                dataclasses.replace(scfg, num_wires=n), pool)
+            patches.append(p)
+            w0s.append(w0 + start)
+            t0s.append(t0)
+        # one scatter over every plane's patches: plane p's wire origins
+        # are offset to its rows of the concatenated axis
+        tall = dataclasses.replace(
+            scfg, num_wires=sum(s.num_wires for s in specs))
+        return state._replace(grid=scatter_add(
+            jnp.concatenate(patches), jnp.concatenate(w0s),
+            jnp.concatenate(t0s), tall))
+
     def fn(state: SimState) -> SimState:
         if not multi:
             return state._replace(grid=compute_charge_grid(
                 state.kf, state.depos, cfg, pool=pool))
+        if concat:
+            return concat_fn(state)
         if not stacked:
             return loop_fn(state)
         from repro.tune import autotune, registry
@@ -372,6 +465,8 @@ def convolve_stage(cfg: LArTPCConfig, resp,
     resolved strategies are not uniformly "rfft2" or the responses disagree
     on padded shape."""
     multi = cfg.num_planes > 1
+    concat = concat_planes(cfg)
+    specs = _selected_specs(cfg, planes)
     resps = _as_plane_responses(cfg, resp, planes)
     stacked = (multi and resolve_plane_batching(cfg) == "stacked"
                and len({r.pad_shape for r in resps}) == 1)
@@ -406,6 +501,10 @@ def convolve_stage(cfg: LArTPCConfig, resp,
         if not multi:
             return state._replace(
                 signal=fft_convolve(state.grid, resps[0], cfg.fft_strategy))
+        if concat:
+            return state._replace(signal=_per_plane(
+                specs, state.grid, lambda i, spec, g: fft_convolve(
+                    g, resps[i], cfg.fft_strategy)))
         if not stacked:
             return loop_fn(state)
         w, t = state.grid.shape[-2:]
@@ -432,23 +531,40 @@ def noise_stage(cfg: LArTPCConfig,
     per-plane loop)."""
     specs = _selected_specs(cfg, planes)
     multi = cfg.num_planes > 1
+    concat = concat_planes(cfg)
     stacked = multi and resolve_plane_batching(cfg) == "stacked"
+    # the spectrum and the gain are built with the graph, outside the
+    # batched executor's per-event map, so that every executor computes
+    # them, and divides by the gain, alike
+    amp = noise_spectrum(cfg)
+    denom = jnp.maximum(cfg.adc_per_electron, 1e-30)
+
+    def draw(key: jax.Array, rows: int) -> jax.Array:
+        return sample_noise_rows(key, rows, amp, cfg.num_ticks)
 
     def fn(state: SimState) -> SimState:
-        denom = jnp.maximum(cfg.adc_per_electron, 1e-30)
         if not multi:
             return state._replace(
-                signal=state.signal + simulate_noise(state.kn, cfg) / denom)
+                signal=state.signal + draw(state.kn, cfg.num_wires) / denom)
+        if concat:
+            return state._replace(signal=_per_plane(
+                specs, state.signal, lambda i, spec, sig: sig + draw(
+                    jax.random.fold_in(state.kn, spec.index),
+                    spec.num_wires) / denom))
         if stacked:
-            noise = jax.vmap(lambda k: simulate_noise(k, cfg))(
+            noise = jax.vmap(lambda k: draw(k, cfg.num_wires))(
                 plane_fold_keys(state.kn, specs))
         else:
             noise = jnp.stack([
-                simulate_noise(jax.random.fold_in(state.kn, spec.index), cfg)
+                draw(jax.random.fold_in(state.kn, spec.index), cfg.num_wires)
                 for spec in specs])
         return state._replace(signal=state.signal + noise / denom)
 
-    return Stage("noise", fn)
+    # a batch draws its events' noise one at a time: batched over events,
+    # the TPU compiler returns wrong inverse transforms for some shapes
+    # (0.35 relative error at 2 x 3,456 or 4 x 2,400 rows x 9,600 ticks,
+    # PERF.md), the event-at-a-time draw is right at every shape measured
+    return Stage("noise", fn, per_event=True)
 
 
 def digitize_stage(cfg: LArTPCConfig) -> Stage:
@@ -472,6 +588,8 @@ def deconvolve_stage(cfg: LArTPCConfig, resp=None,
                                        measured_signal)
 
     multi = cfg.num_planes > 1
+    concat = concat_planes(cfg)
+    specs = _selected_specs(cfg, planes)
     filts = tuple(make_deconv_filter(r, cfg)
                   for r in _as_plane_responses(cfg, resp, planes))
 
@@ -480,6 +598,10 @@ def deconvolve_stage(cfg: LArTPCConfig, resp=None,
         if not multi:
             return state._replace(
                 decon=deconvolve(meas, filts[0], cfg.deconv_strategy))
+        if concat:
+            return state._replace(decon=_per_plane(
+                specs, meas, lambda i, spec, m: deconvolve(
+                    m, filts[i], cfg.deconv_strategy)))
         decon = jnp.stack([
             deconvolve(meas[i], f, cfg.deconv_strategy)
             for i, f in enumerate(filts)])
@@ -497,13 +619,21 @@ def hit_find_stage(cfg: LArTPCConfig,
 
     specs = _selected_specs(cfg, planes)
     multi = cfg.num_planes > 1
+    concat = concat_planes(cfg)
 
     def fn(state: SimState) -> SimState:
         if not multi:
             return state._replace(
                 hits=find_hits(state.decon, cfg, cfg.hitfind_strategy))
-        per_plane = [find_hits(state.decon[i], cfg, cfg.hitfind_strategy)
-                     for i in range(len(specs))]
+        if concat:
+            per_plane = []
+            for spec, (start, n) in zip(specs, plane_rows(specs)):
+                with jax.named_scope(spec.kind):
+                    per_plane.append(find_hits(state.decon[start:start + n],
+                                               cfg, cfg.hitfind_strategy))
+        else:
+            per_plane = [find_hits(state.decon[i], cfg, cfg.hitfind_strategy)
+                         for i in range(len(specs))]
         hits = jax.tree.map(lambda *xs: jnp.stack(xs), *per_plane)
         return state._replace(hits=hits)
 

@@ -38,6 +38,8 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from repro.config import plane_specs
+
 #: out-of-frame margin, as a multiple of the readout extent: the rasterizer
 #: clips patch origins to the grid, so mildly out-of-range coordinates (the
 #: rotated-plane corner overhangs of a multi-plane projection) are harmless —
@@ -125,9 +127,9 @@ def check_detector_depos(depos, cfg, max_depos: Optional[int] = None
         if np.any(np.isfinite(s) & (s <= 0)):
             reasons.append(f"non-positive {name} "
                            f"(min {float(np.nanmin(s)):g})")
+    wires = max(spec.num_wires for spec in plane_specs(cfg))
     reasons += _bounds_reason("wire", leaves["wire"],
-                              -FRAME_MARGIN * cfg.num_wires,
-                              FRAME_MARGIN * cfg.num_wires)
+                              -FRAME_MARGIN * wires, FRAME_MARGIN * wires)
     reasons += _bounds_reason("tick", leaves["tick"],
                               -FRAME_MARGIN * cfg.num_ticks,
                               FRAME_MARGIN * cfg.num_ticks)

@@ -138,7 +138,7 @@ def run_grad_smoke(args) -> int:
     # traced loss accepts both replicated and event-sharded operands
     def loss(theta, keys, depos, target):
         graph = build_sim_graph(spec.apply(fcfg, theta), None)
-        out = jax.vmap(graph.run)(keys, depos)
+        out = graph.run_events(keys, depos)
         return jnp.mean((out.adc - target) ** 2)
 
     grad_fn = jax.jit(jax.grad(loss))

@@ -1,6 +1,7 @@
 """LArTPC simulation launcher (the paper's workload):
 
-    python -m repro.launch.sim [--smoke] [--events N] [--batch-events E]
+    python -m repro.launch.sim [--config ID] [--smoke] [--events N]
+                               [--batch-events E]
                                [--pipeline fig3|fig4] [--tune] [--retune]
                                [--strategy <scatter>] [--trace-dir DIR]
                                [--recon] [--set key=value ...]
@@ -39,7 +40,8 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.cache import enable_compile_cache
-from repro.config import LArTPCConfig, apply_overrides, get_config
+from repro.config import (LArTPCConfig, apply_overrides, concat_planes,
+                          get_config)
 from repro.core import generate_depos, simulate
 from repro.core.batch import (empty_event, event_keys, make_batched_sim_fn,
                               pack_events, screen_events, shard_events)
@@ -324,6 +326,21 @@ def stream_simulate(cfg: LArTPCConfig, num_events: int, batch_events: int = 1,
     return stats
 
 
+def with_planes(cfg: LArTPCConfig, num_planes: int) -> LArTPCConfig:
+    """``cfg`` read out on ``num_planes`` planes (the ``--planes`` flag).
+
+    The flag counts planes of ``num_wires`` wires each; a deployment that
+    gives its planes their own wire counts (``concat_planes``) fixes its
+    planes itself, and is refused rather than cut to planes it does not
+    have."""
+    if concat_planes(cfg):
+        raise ValueError(
+            f"--planes {num_planes}: config {cfg.name!r} gives its planes "
+            f"their own wire counts (plane_wires={tuple(cfg.plane_wires)}); "
+            "it sets its own planes, so drop --planes")
+    return apply_overrides(cfg, {"num_planes": num_planes})
+
+
 def _run_fig3(cfg: LArTPCConfig, num_events: int, seed: int) -> None:
     """The faithful per-depo host-loop baseline (paper Fig. 3)."""
     resp = make_response(cfg)
@@ -343,6 +360,9 @@ def _run_fig3(cfg: LArTPCConfig, num_events: int, seed: int) -> None:
 
 def main():
     ap = argparse.ArgumentParser()
+    ap.add_argument("--config", default="lartpc-uboone",
+                    help="registry deployment to simulate, e.g. "
+                         "lartpc-uboone3p (MicroBooNE's three planes)")
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--events", type=int, default=2)
     ap.add_argument("--batch-events", type=int, default=1,
@@ -395,11 +415,11 @@ def main():
         raise SystemExit("--resume needs --journal PATH")
     enable_compile_cache()
 
-    cfg = get_config("lartpc-uboone", smoke=args.smoke)
+    cfg = get_config(args.config, smoke=args.smoke)
     if args.depos:
         cfg = apply_overrides(cfg, {"num_depos": args.depos})
     if args.planes:
-        cfg = apply_overrides(cfg, {"num_planes": args.planes})
+        cfg = with_planes(cfg, args.planes)
     if args.pipeline:
         cfg = apply_overrides(cfg, {"pipeline": args.pipeline})
     if args.check_finite:

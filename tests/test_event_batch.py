@@ -214,3 +214,42 @@ class TestSharding:
                       shard_events(batch))
         np.testing.assert_array_equal(np.asarray(out.adc),
                                       np.asarray(ref.adc))
+
+
+def test_batched_executor_draws_noise_one_event_at_a_time():
+    """The batch's noise stage is one loop over its events (``lax.map``),
+    not a draw batched over them: batched over events, the TPU compiler
+    returns wrong inverse transforms for some shapes (PERF.md)."""
+    import re
+
+    keys = event_keys(jax.random.key(0), range(2))
+    batch = pack_events(_events(RAGGED[:2]))
+    text = make_batched_sim_fn(CFG).lower(keys, batch).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    assert "jit(run)/noise/while" in names
+    assert not any(n.startswith("jit(run)/vmap(noise)") for n in names)
+
+
+def test_only_what_the_noise_writes_leaves_its_event_loop():
+    """The noise loop returns the signal alone: the fields of the state it
+    leaves as they are (the grid, the depos, the keys) are forwarded past
+    the loop by ``lax.map``, not copied through it event by event."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    keys = event_keys(jax.random.key(0), range(2))
+    batch = pack_events(_events(RAGGED[:2]))
+    top = jax.make_jaxpr(make_batched_sim_fn(CFG))(keys, batch)
+
+    def scans(jaxpr):
+        for eqn in jaxpr.eqns:
+            if (eqn.primitive.name == "scan"
+                    and "noise" in str(eqn.source_info.name_stack)):
+                yield eqn
+            for p in eqn.params.values():
+                sub = p.jaxpr if isinstance(p, ClosedJaxpr) else p
+                if isinstance(sub, Jaxpr):
+                    yield from scans(sub)
+
+    (loop,) = scans(top.jaxpr)
+    assert [v.aval.shape for v in loop.outvars] == [
+        (2, CFG.num_wires, CFG.num_ticks)]
