@@ -3,9 +3,10 @@ from repro.config import LArTPCConfig, register
 
 
 def full() -> LArTPCConfig:
-    # 2560 wires x 9592 ticks, 100k depos; the scatter strategy follows the
-    # backend's default (lane_rows on a TPU, xla elsewhere)
-    return LArTPCConfig(scatter_strategy="auto")
+    # 2560 wires x 9592 ticks, 100k depos; the scatter and convolve
+    # strategies follow the backend's defaults (lane_rows and direct on a
+    # TPU, xla and rfft2 elsewhere)
+    return LArTPCConfig(scatter_strategy="auto", fft_strategy="auto")
 
 
 def smoke() -> LArTPCConfig:
@@ -28,7 +29,7 @@ def full_3p() -> LArTPCConfig:
         plane_angles_deg=(60.0, -60.0, 0.0),
         plane_types=("induction", "induction", "collection"),
         plane_wires=(2400, 2400, 3456),
-        scatter_strategy="auto")
+        scatter_strategy="auto", fft_strategy="auto")
 
 
 def smoke_3p() -> LArTPCConfig:

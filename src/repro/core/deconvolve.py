@@ -22,12 +22,15 @@ hole). Both filters here regularize that inversion:
 
 A filter is *represented as* a ``DetectorResponse`` (freq = G at the same
 ``pad_shape``), so applying it is literally the convolve stage's math and
-both ``fft_convolve`` layout strategies work on it unchanged. Two candidates
-register under the ``deconvolve`` op:
+both ``fft_convolve`` spectral layouts (``SPECTRAL_LAYOUTS``) work on it
+unchanged. The filter keeps the FORWARD kernel as its ``kernel``, so the
+real-space ``direct`` convolve strategy would apply the forward response:
+it is never used here. Two candidates register under the ``deconvolve`` op:
 
   rfft2     : direct half-spectrum multiply (the rfft2 convolve layout).
   fft_reuse : dispatch through ``fft_convolve``'s own tuned strategy table —
-              whatever layout won the convolve tuning wins here too.
+              whatever spectral layout won the convolve tuning wins here
+              too; a winner that is not a spectral layout maps to rfft2.
 """
 from __future__ import annotations
 
@@ -37,12 +40,16 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import LArTPCConfig
-from repro.core.fft_conv import fft_convolve, fft_convolve_rfft2
+from repro.core.fft_conv import (fft_convolve, fft_convolve_rfft2,
+                                 fft_shape, resolve_fft_strategy)
 from repro.core.response import DetectorResponse
 from repro.tune.registry import register_strategy, set_default
 
 #: filter families ``make_deconv_filter`` accepts
 DECONV_FILTERS = ("wiener", "gaussian")
+#: the ``fft_convolve`` strategies that multiply by ``freq``, and so apply
+#: an inverse filter; ``fft_reuse`` resolves among these alone
+SPECTRAL_LAYOUTS = ("rfft2", "fft2")
 
 
 def measured_signal(adc: jax.Array, cfg: LArTPCConfig) -> jax.Array:
@@ -139,8 +146,12 @@ def deconvolve_rfft2(meas: jax.Array, filt: DetectorResponse) -> jax.Array:
                         "inverse multiply")
 def deconvolve_fft_reuse(meas: jax.Array, filt: DetectorResponse) -> jax.Array:
     # "auto" resolves from the fft_convolve tuning cache (plane-keyed) at
-    # trace time — the layout that won the forward convolve wins here too
-    return fft_convolve(meas, filt, strategy="auto")
+    # trace time — the layout that won the forward convolve wins here too,
+    # if it is spectral: ``direct`` would convolve with the forward kernel
+    name = resolve_fft_strategy("auto", meas.shape, filt)
+    if name not in SPECTRAL_LAYOUTS:
+        name = "rfft2"
+    return fft_convolve(meas, filt, strategy=name)
 
 
 set_default("deconvolve", "rfft2")
@@ -162,11 +173,8 @@ def deconvolve(meas: jax.Array, filt: DetectorResponse,
     if strategy is None:
         strategy = registry.default_strategy("deconvolve")
     elif strategy == "auto":
-        shape = {"num_wires": meas.shape[0], "num_ticks": meas.shape[1],
-                 "response_wires": filt.kernel.shape[0],
-                 "response_ticks": filt.kernel.shape[1],
-                 "plane": filt.plane}
-        strategy = autotune.resolve("deconvolve", None, shape=shape).strategy
+        strategy = autotune.resolve("deconvolve", None,
+                                    shape=fft_shape(meas.shape, filt)).strategy
     try:
         strat = registry.get_strategy("deconvolve", strategy)
     except KeyError:

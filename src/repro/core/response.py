@@ -23,8 +23,10 @@ class DetectorResponse(NamedTuple):
     the container is direction-agnostic: the recon chain's inverse filters
     (``repro.core.deconvolve.make_deconv_filter``) are DetectorResponses
     too — same ``pad_shape``/``plane``, ``freq`` holding the regularized
-    inverse — so every ``fft_convolve`` layout and the plane-keyed tuning
-    bucket apply to deconvolution unchanged.
+    inverse — so the spectral ``fft_convolve`` layouts and the plane-keyed
+    tuning bucket apply to deconvolution unchanged. ``kernel`` stays the
+    forward kernel, which the real-space ``direct`` strategy reads: it
+    applies to forward responses only.
     """
 
     kernel: jax.Array       # (response_wires, response_ticks) real-space response

@@ -48,7 +48,7 @@ import jax.numpy as jnp
 
 from repro.config import LArTPCConfig, concat_planes, plane_specs
 from repro.core.depo import DepoSet
-from repro.core.fft_conv import digitize, fft_convolve
+from repro.core.fft_conv import digitize, fft_convolve, resolve_fft_strategy
 from repro.core.noise import noise_spectrum, sample_noise_rows
 from repro.core.response import DetectorResponse
 
@@ -471,26 +471,6 @@ def convolve_stage(cfg: LArTPCConfig, resp,
     stacked = (multi and resolve_plane_batching(cfg) == "stacked"
                and len({r.pad_shape for r in resps}) == 1)
 
-    def resolved_names(grid_shape):
-        """Per-plane strategy names, mirroring ``fft_convolve`` dispatch."""
-        from repro.tune import autotune, registry
-
-        names = []
-        for r in resps:
-            s = cfg.fft_strategy
-            if s is None:
-                s = registry.default_strategy("fft_convolve")
-            elif s == "auto":
-                shape = {"num_wires": grid_shape[0],
-                         "num_ticks": grid_shape[1],
-                         "response_wires": r.kernel.shape[0],
-                         "response_ticks": r.kernel.shape[1],
-                         "plane": r.plane}
-                s = autotune.resolve("fft_convolve", None,
-                                     shape=shape).strategy
-            names.append(s)
-        return names
-
     def loop_fn(state: SimState) -> SimState:
         signal = jnp.stack([
             fft_convolve(state.grid[i], r, cfg.fft_strategy)
@@ -508,7 +488,8 @@ def convolve_stage(cfg: LArTPCConfig, resp,
         if not stacked:
             return loop_fn(state)
         w, t = state.grid.shape[-2:]
-        if any(n != "rfft2" for n in resolved_names((w, t))):
+        if any(resolve_fft_strategy(cfg.fft_strategy, (w, t), r) != "rfft2"
+               for r in resps):
             return loop_fn(state)
         from repro.core.fft_conv import _pad_grid
 

@@ -1,8 +1,8 @@
 """FFT-convolution and response-transform unit/property tests.
 
 Covers the ISSUE 5 satellite fixes:
-  * both ``fft_convolve`` strategies run on narrow (bfloat16) charge grids
-    and return one identical dtype (the bf16 path used to crash rfft2 and
+  * every ``fft_convolve`` strategy runs on narrow (bfloat16) charge grids
+    and returns one identical dtype (the bf16 path used to crash rfft2 and
     return bf16 from fft2);
   * ``response.next_fast_len`` is provably minimal 5-smooth >= n;
   * ``fft_conv._full_spectrum`` reconstructs the exact Hermitian tail at
@@ -25,7 +25,7 @@ from repro.core.response import make_response, next_fast_len
 CFG = LArTPCConfig(num_wires=64, num_ticks=256, num_depos=64,
                    response_wires=11, response_ticks=48)
 
-STRATEGIES = ("rfft2", "fft2")
+STRATEGIES = ("rfft2", "fft2", "direct")
 PATCH_DTYPES = ("float32", "bfloat16")
 
 
@@ -34,7 +34,7 @@ class TestConvolveDtypes:
     @pytest.mark.parametrize("patch_dtype", PATCH_DTYPES)
     def test_strategy_runs_on_patch_dtype(self, strategy, patch_dtype):
         """Every (strategy, patch dtype) pair runs and returns float32 —
-        the single upcast lives in ``_pad_grid``."""
+        the single upcast lives in ``_widen``."""
         resp = make_response(CFG)
         grid = jax.random.uniform(
             jax.random.key(1), (CFG.num_wires, CFG.num_ticks),
@@ -45,17 +45,18 @@ class TestConvolveDtypes:
 
     @pytest.mark.parametrize("patch_dtype", PATCH_DTYPES)
     def test_strategies_agree_per_dtype(self, patch_dtype):
-        """rfft2 and fft2 see the same upcast input, so they agree to FFT
-        roundoff and share an output dtype."""
+        """Every strategy sees the same upcast input, so they agree with
+        rfft2 to roundoff and share its output dtype."""
         resp = make_response(CFG)
         grid = jax.random.uniform(
             jax.random.key(2), (CFG.num_wires, CFG.num_ticks),
             dtype=jnp.float32).astype(jnp.dtype(patch_dtype))
         outs = [fft_convolve(grid, resp, s) for s in STRATEGIES]
-        assert outs[0].dtype == outs[1].dtype
         scale = float(jnp.abs(outs[0]).max()) + 1e-30
-        np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(outs[1]),
-                                   atol=1e-4 * scale)
+        for out in outs[1:]:
+            assert out.dtype == outs[0].dtype
+            np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(out),
+                                       atol=1e-4 * scale)
 
     @pytest.mark.parametrize("strategy", STRATEGIES)
     def test_full_chain_runs_bf16_patches(self, strategy):

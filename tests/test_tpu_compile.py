@@ -129,3 +129,24 @@ def test_charge_grid_scatter_loop(streaming_program, strategy,
             loops.append(name.group(1))
     assert any("scatter-add" in op for op in loops) == per_patch_loop, loops
     assert loops, "the charge grid keeps a loop: per patch or per chunk"
+
+
+def test_direct_convolve_compiles(one_chip):
+    """The TPU's default convolve, ``direct``, over a batch of 2 full
+    planes: one convolution at f32 precision (``highest`` operands), no
+    FFT, within one chip's memory."""
+    from repro.core.fft_conv import fft_convolve
+    from repro.core.response import make_response
+
+    assert default_strategy("fft_convolve", "tpu") == "direct"
+    resp = make_response(FULL)
+    grids = jax.ShapeDtypeStruct((2, FULL.num_wires, FULL.num_ticks),
+                                 jnp.float32, sharding=one_chip)
+    fn = jax.jit(jax.vmap(lambda g: fft_convolve(g, resp, "direct")))
+    compiled = fn.lower(grids).compile()
+    text = compiled.as_text()
+    convs = [line for line in text.splitlines() if " convolution(" in line]
+    assert convs and all("operand_precision={highest,highest}" in line
+                         for line in convs), convs
+    assert " fft(" not in text
+    assert _total_bytes(compiled) < HBM_BYTES

@@ -30,6 +30,7 @@ FAKE_TIMES = {"xla": 3.0, "sort_segment": 2.0, "lane_rows": 2.5,
               "pallas": 1.0, "pallas_compact": 1.5,
               "unfused": 2.0, "unfused_bf16": 2.5, "fused_pallas": 1.0,
               "fused_pallas_compact": 1.5, "rfft2": 1.0, "fft2": 2.0,
+              "direct": 3.0,
               "scan": 2.0}  # hit_find: "pallas" (1.0) fake-wins over "scan"
 
 
@@ -66,7 +67,8 @@ class TestRegistry:
             "unfused", "unfused_bf16", "fused_pallas",
             "fused_pallas_compact", "fused_pallas_multiplane",
             "fused_pallas_multiplane_compact", "multiplane_xla"}
-        assert set(tune.strategies("fft_convolve")) == {"rfft2", "fft2"}
+        assert set(tune.strategies("fft_convolve")) == {"rfft2", "fft2",
+                                                       "direct"}
 
     def test_unknown_names_raise_with_known_list(self):
         with pytest.raises(KeyError, match="scatter_add"):
@@ -113,7 +115,8 @@ class TestRegistry:
     def test_backend_defaults(self):
         assert tune.default_strategy("scatter_add", "cpu") == "xla"
         assert tune.default_strategy("scatter_add", "tpu") == "lane_rows"
-        assert tune.default_strategy("fft_convolve", "tpu") == "rfft2"
+        assert tune.default_strategy("fft_convolve", "cpu") == "rfft2"
+        assert tune.default_strategy("fft_convolve", "tpu") == "direct"
 
 
 class TestAutotuner:
