@@ -304,9 +304,9 @@ def phase_multichip(cfg, n_dev: int, num_events: int = 8,
     from repro.core.rasterize import rasterize
     from repro.core.response import make_distributed_plane_responses
     from repro.core.scatter import scatter_xla
+    from repro.core.sharding import use_mesh
     from repro.launch.mesh import make_mesh
     from repro.launch.sim import make_streaming_sim_fn
-    from repro.parallel.sharding import use_mesh
 
     devices = jax.devices()[:n_dev]
     say("m", f"event-sharded stream: {num_events} events, batch "

@@ -3,10 +3,9 @@
 The paper's portability argument is program-level: fusion, data movement,
 and collective traffic decide whether a port is fast, and those properties
 live in the *compiled* program, not the Python source. PR 9 asserted one of
-them (collective counts) with an inline ``txt.count(...)`` inside a test;
-``launch/hlo_cost.py`` parses the same text for a cost model. This module is
-the shared API both — and the contract auditor (``repro.analysis.audit``) —
-read compiled programs through:
+them (collective counts) with an inline ``txt.count(...)`` inside a test.
+This module is the shared API the tests and the contract auditor
+(``repro.analysis.audit``) read compiled programs through:
 
   collective_counts    : instructions per collective kind (all-reduce,
                          reduce-scatter, all-to-all, all-gather,
@@ -43,7 +42,7 @@ from typing import Callable, Dict, Iterator, Set, Tuple
 COLLECTIVE_KINDS = ("all-gather", "all-reduce", "all-to-all",
                     "collective-permute", "reduce-scatter")
 
-#: dtype tokens that appear in HLO shapes (subset of launch/hlo_cost._BYTES)
+#: dtype tokens that appear in HLO shapes
 DTYPE_TOKENS = ("pred", "s4", "s8", "s16", "s32", "s64", "u4", "u8", "u16",
                 "u32", "u64", "f8e4m3fn", "f8e5m2", "f16", "bf16", "f32",
                 "f64", "c64", "c128")

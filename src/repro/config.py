@@ -1,132 +1,15 @@
-"""Config system: typed dataclasses, a registry, and CLI overrides.
+"""Config system: the simulator's frozen dataclass, a registry, and overrides.
 
-Every selectable architecture registers a ``ModelConfig`` factory under an id
-(``--arch <id>``). Configs are plain frozen dataclasses so they hash and can be
+Every deployment (a detector geometry at its published widths) registers a
+full-size and a smoke-size ``LArTPCConfig`` factory under an id
+(``--config <id>``). Configs are frozen dataclasses so they hash and can be
 closed over by jit without retracing surprises.
 """
 from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, replace
-from typing import Any, Callable, Dict, NamedTuple, Optional, Sequence, Tuple
-
-# ---------------------------------------------------------------------------
-# Model configs
-# ---------------------------------------------------------------------------
-
-
-@dataclass(frozen=True)
-class MoEConfig:
-    """Mixture-of-experts block config (DeepSeek-style fine-grained MoE)."""
-
-    num_experts: int = 0          # routed experts
-    num_shared: int = 0           # always-on shared experts
-    top_k: int = 0
-    expert_ff: int = 0            # per-expert hidden size
-    router_aux_weight: float = 0.001
-    # layers [first_moe_layer, num_layers) are MoE; earlier layers are dense
-    first_moe_layer: int = 1
-    dense_ff: int = 0             # ff size of the dense (non-MoE) layers
-    capacity_factor: float = 1.25  # per-expert token capacity multiplier
-
-
-@dataclass(frozen=True)
-class MLAConfig:
-    """Multi-head latent attention (DeepSeek-V2)."""
-
-    kv_lora_rank: int = 512
-    q_lora_rank: int = 0          # 0 = full-rank q projection
-    rope_head_dim: int = 64
-    nope_head_dim: int = 128
-    v_head_dim: int = 128
-
-
-@dataclass(frozen=True)
-class SSMConfig:
-    """Mamba-2 SSD block config."""
-
-    state_dim: int = 128
-    head_dim: int = 64
-    expand: int = 2
-    conv_width: int = 4
-    chunk: int = 256              # SSD chunk length
-
-
-@dataclass(frozen=True)
-class RGLRUConfig:
-    """RecurrentGemma RG-LRU block config."""
-
-    lru_width: int = 0            # 0 -> d_model
-    conv_width: int = 4
-    block_pattern: Tuple[str, ...] = ("recurrent", "recurrent", "attention")
-
-
-@dataclass(frozen=True)
-class ModelConfig:
-    name: str = "model"
-    family: str = "dense"         # dense | moe | ssm | hybrid | vlm | audio | lartpc
-    num_layers: int = 2
-    d_model: int = 128
-    num_heads: int = 2
-    num_kv_heads: int = 2
-    head_dim: int = 0             # 0 -> d_model // num_heads
-    d_ff: int = 256
-    vocab_size: int = 1000
-    max_seq_len: int = 8192
-    # attention details
-    attn_kind: str = "global"     # global | local | local_global | none
-    window_size: int = 4096       # for local attention
-    qk_norm: bool = False
-    attn_logit_softcap: float = 0.0
-    final_logit_softcap: float = 0.0
-    rope_theta: float = 10000.0
-    # mlp
-    mlp_kind: str = "swiglu"      # swiglu | squared_relu | gelu | relu
-    # norm / embeddings
-    norm_kind: str = "rmsnorm"    # rmsnorm | layernorm
-    tie_embeddings: bool = False
-    embedding_scale: bool = False  # gemma-style sqrt(d_model) input scaling
-    # sub-configs
-    moe: Optional[MoEConfig] = None
-    mla: Optional[MLAConfig] = None
-    ssm: Optional[SSMConfig] = None
-    rglru: Optional[RGLRUConfig] = None
-    # enc-dec
-    is_encoder_decoder: bool = False
-    num_encoder_layers: int = 0
-    # multimodal stub frontends: number of precomputed embedding positions
-    frontend: str = "none"        # none | vision | speech
-    frontend_tokens: int = 0
-    # numerics
-    dtype: str = "bfloat16"
-    param_dtype: str = "float32"
-    # remat: none | full | selective
-    remat: str = "selective"
-
-    #: embedding/unembedding tables are padded to a multiple of this so the
-    #: vocab dim shards cleanly over the model axis (Megatron convention)
-    vocab_pad_to: int = 256
-
-    @property
-    def padded_vocab(self) -> int:
-        m = self.vocab_pad_to
-        return (self.vocab_size + m - 1) // m * m
-
-    @property
-    def resolved_head_dim(self) -> int:
-        return self.head_dim or (self.d_model // self.num_heads)
-
-    def param_count(self) -> int:
-        """Approximate parameter count (used for 6ND model flops)."""
-        from repro.models.model import count_params_analytic
-
-        return count_params_analytic(self)
-
-    def active_param_count(self) -> int:
-        from repro.models.model import count_params_analytic
-
-        return count_params_analytic(self, active_only=True)
-
+from typing import Any, Callable, Dict, NamedTuple, Sequence, Tuple
 
 # ---------------------------------------------------------------------------
 # LArTPC sim config (the paper's own workload)
@@ -377,118 +260,46 @@ def face_extent_mm(cfg: "LArTPCConfig") -> Tuple[float, float]:
 
 
 # ---------------------------------------------------------------------------
-# Shapes (assigned input-shape set)
-# ---------------------------------------------------------------------------
-
-
-@dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    kind: str                     # train | prefill | decode
-    seq_len: int
-    global_batch: int
-
-
-SHAPES: Dict[str, ShapeConfig] = {
-    "train_4k": ShapeConfig("train_4k", "train", 4_096, 256),
-    "prefill_32k": ShapeConfig("prefill_32k", "prefill", 32_768, 32),
-    "decode_32k": ShapeConfig("decode_32k", "decode", 32_768, 128),
-    "long_500k": ShapeConfig("long_500k", "decode", 524_288, 1),
-}
-
-
-# ---------------------------------------------------------------------------
-# Run/training config
-# ---------------------------------------------------------------------------
-
-
-@dataclass(frozen=True)
-class ParallelConfig:
-    data_axis: str = "data"
-    model_axis: str = "model"
-    pod_axis: str = "pod"
-    fsdp: bool = True              # shard params over data axis
-    expert_axis: str = "model"     # EP placement
-    sequence_parallel: bool = False
-    grad_compression: str = "none"  # none | int8_ef
-    microbatches: int = 1
-    remat_policy: str = "selective"
-
-
-@dataclass(frozen=True)
-class OptimizerConfig:
-    name: str = "adamw"
-    lr: float = 3e-4
-    warmup_steps: int = 100
-    total_steps: int = 1000
-    weight_decay: float = 0.1
-    b1: float = 0.9
-    b2: float = 0.95
-    eps: float = 1e-8
-    grad_clip: float = 1.0
-    schedule: str = "cosine"       # cosine | linear | constant
-
-
-@dataclass(frozen=True)
-class CheckpointConfig:
-    directory: str = "/tmp/repro_ckpt"
-    every_steps: int = 50
-    keep: int = 3
-    async_save: bool = True
-
-
-@dataclass(frozen=True)
-class TrainConfig:
-    model: Any = None
-    shape: ShapeConfig = SHAPES["train_4k"]
-    parallel: ParallelConfig = ParallelConfig()
-    optimizer: OptimizerConfig = OptimizerConfig()
-    checkpoint: CheckpointConfig = CheckpointConfig()
-    seed: int = 0
-    log_every: int = 10
-    straggler_deadline_s: float = 0.0   # 0 disables
-
-
-# ---------------------------------------------------------------------------
 # Registry
 # ---------------------------------------------------------------------------
 
-_REGISTRY: Dict[str, Callable[[], Any]] = {}
-_SMOKE_REGISTRY: Dict[str, Callable[[], Any]] = {}
+_REGISTRY: Dict[str, Callable[[], LArTPCConfig]] = {}
+_SMOKE_REGISTRY: Dict[str, Callable[[], LArTPCConfig]] = {}
 
 
-def register(arch_id: str, full: Callable[[], Any], smoke: Callable[[], Any]) -> None:
-    _REGISTRY[arch_id] = full
-    _SMOKE_REGISTRY[arch_id] = smoke
+def register(config_id: str, full: Callable[[], LArTPCConfig],
+             smoke: Callable[[], LArTPCConfig]) -> None:
+    _REGISTRY[config_id] = full
+    _SMOKE_REGISTRY[config_id] = smoke
 
 
-def get_config(arch_id: str, smoke: bool = False):
+def get_config(config_id: str, smoke: bool = False) -> LArTPCConfig:
     import repro.configs  # noqa: F401  (triggers registration)
 
     table = _SMOKE_REGISTRY if smoke else _REGISTRY
-    if arch_id not in table:
-        raise KeyError(f"unknown arch {arch_id!r}; known: {sorted(table)}")
-    return table[arch_id]()
+    if config_id not in table:
+        raise KeyError(f"unknown deployment {config_id!r}; known: "
+                       f"{sorted(table)}")
+    return table[config_id]()
 
 
 def list_archs() -> Sequence[str]:
+    """The registered deployment ids, sorted."""
     import repro.configs  # noqa: F401
 
-    return sorted(_REGISTRY)
+    return tuple(sorted(_REGISTRY))
 
 
-def apply_overrides(cfg, overrides: Dict[str, Any]):
-    """dot.path=value overrides onto nested frozen dataclasses."""
+def apply_overrides(cfg: LArTPCConfig, overrides: Dict[str, Any]) -> LArTPCConfig:
+    """``field=value`` overrides onto a frozen config. A string value (from
+    ``--set``) is parsed to the field's int, float or bool type; a list (from
+    a JSON file) becomes a tuple, so the config still hashes (jit keys on
+    it). An unknown field raises ``KeyError``."""
+    fields = {f.name: f for f in dataclasses.fields(cfg)}
     for key, value in overrides.items():
-        parts = key.split(".")
-        cfg = _apply_one(cfg, parts, value)
-    return cfg
-
-
-def _apply_one(cfg, parts, value):
-    if len(parts) == 1:
-        fld = {f.name: f for f in dataclasses.fields(cfg)}[parts[0]]
-        typ = fld.type
+        if key not in fields:
+            raise KeyError(f"{type(cfg).__name__} has no field {key!r}")
+        typ = fields[key].type
         if isinstance(value, str):
             if typ in ("int", int):
                 value = int(value)
@@ -497,9 +308,6 @@ def _apply_one(cfg, parts, value):
             elif typ in ("bool", bool):
                 value = value.lower() in ("1", "true", "yes")
         elif isinstance(value, list):
-            # a JSON list: the frozen config hashes, so a list field would
-            # not (jit keys on the config)
             value = tuple(value)
-        return replace(cfg, **{parts[0]: value})
-    sub = getattr(cfg, parts[0])
-    return replace(cfg, **{parts[0]: _apply_one(sub, parts[1:], value)})
+        cfg = replace(cfg, **{key: value})
+    return cfg

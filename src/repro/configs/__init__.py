@@ -1,30 +1,5 @@
-"""Architecture registry: one module per assigned architecture.
+"""Deployment registry: one module per detector deployment.
 
-Importing this package registers every arch under its ``--arch <id>``.
+Importing this package registers every deployment under its ``--config <id>``.
 """
-from repro.configs import (  # noqa: F401
-    deepseek_moe_16b,
-    deepseek_v2_236b,
-    gemma2_2b,
-    internvl2_1b,
-    lartpc_uboone,
-    mamba2_780m,
-    nemotron4_15b,
-    qwen3_32b,
-    recurrentgemma_2b,
-    seamless_m4t_large_v2,
-    stablelm_12b,
-)
-
-ARCH_IDS = [
-    "mamba2-780m",
-    "internvl2-1b",
-    "qwen3-32b",
-    "nemotron-4-15b",
-    "gemma2-2b",
-    "stablelm-12b",
-    "deepseek-moe-16b",
-    "deepseek-v2-236b",
-    "recurrentgemma-2b",
-    "seamless-m4t-large-v2",
-]
+from repro.configs import lartpc_uboone  # noqa: F401

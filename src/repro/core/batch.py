@@ -20,7 +20,7 @@ level:
                      pre-computed pool design; only the additive noise stage
                      differs per event).
   shard_events     : place the event axis across devices via the mesh rules
-                     in ``repro.parallel.sharding`` (logical axis "events").
+                     in ``repro.core.sharding`` (logical axis "events").
 
 Per-event results are bit-identical to calling ``simulate_fig4`` on the same
 padded row (asserted in ``tests/test_event_batch.py``): vmap changes the
@@ -37,9 +37,9 @@ import jax.numpy as jnp
 from repro.config import LArTPCConfig
 from repro.core.depo import DepoSet
 from repro.core.response import DetectorResponse
+from repro.core.sharding import current_mesh, logical, named_sharding
 from repro.core.stages import (SimGraph, SimOutput, build_sim_graph,
                                jit_executor)
-from repro.parallel.sharding import current_mesh, logical, named_sharding
 
 
 class EventBatch(NamedTuple):

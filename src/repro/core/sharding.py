@@ -1,9 +1,9 @@
-"""Logical-axis sharding rules -> concrete PartitionSpecs.
+"""Logical-axis sharding rules for the simulator's arrays -> PartitionSpecs.
 
-Every tensor in the framework is described by *logical* dim names; the rules
-table maps names to mesh axes (DP/FSDP/TP/EP/SP). ``build_spec`` drops any
-mapping whose axis size does not divide the dim — small models gracefully
-lose TP on dims that don't split (e.g. 8 kv-heads on a 16-way model axis).
+Event-batch arrays are described by *logical* dim names (``events``,
+``depos``, ``wires``, ``ticks``); the rule table maps each name to mesh
+axes. ``build_spec`` drops any mapping whose axis size does not divide the
+dim, so a batch that does not split over a mesh axis stays replicated on it.
 """
 from __future__ import annotations
 
@@ -16,78 +16,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 AxisName = Union[str, Tuple[str, ...], None]
 
-# ---------------------------------------------------------------------------
-# Rules
-# ---------------------------------------------------------------------------
-
-#: parameter dims
-PARAM_RULES: Dict[str, AxisName] = {
-    "vocab": "model",
-    "embed": "data",          # FSDP / ZeRO-3: shard the embed dim over data
-    "heads": "model",         # TP: attention heads
-    "kv_heads": "model",
-    "head_dim": None,
-    "mlp": "model",           # TP: MLP hidden
-    "experts": "model",       # EP: routed experts
-    "expert_mlp": None,
-    "kv_lora": None,
-    "layers": None,           # scan axis, never sharded
-    "conv": None,
-    "state": None,
-}
-
-#: activation dims
+#: logical dim -> mesh axes
 ACT_RULES: Dict[str, AxisName] = {
-    "batch": ("pod", "data"),
-    # Megatron-style sequence parallelism: the residual stream (block-level
-    # activations, incl. the remat-saved mix_out/ffn_out) shards its seq dim
-    # over `model`; GSPMD inserts the all-gather before qkv/mlp projections
-    # and the reduce-scatter after. Cuts saved-activation memory by the TP
-    # degree. Divisibility fallback handles seq=1 decode.
-    "seq": "model",
-    "attn_seq": None,   # attention-internal q/k/v seq dim (never forced)
-    "kv_seq": None,
-    "embed": None,
-    "heads": "model",
-    "kv_heads": "model",
-    "head_dim": None,
-    "mlp": "model",
-    "experts": "model",
-    "capacity": None,
-    "vocab": "model",
-    "state": None,
-    # LArTPC sim
     "depos": ("pod", "data", "model"),
     "events": ("pod", "data"),   # event axis of a multi-event batch (DP)
     "wires": "model",
     "ticks": None,
 }
-
-
-#: DP-heavy activation rules for small archs whose head count does not
-#: divide the model axis (e.g. 14 heads on 16): the batch claims every mesh
-#: axis (pure data parallelism, the production layout for ~1-2B models);
-#: per-tensor divisibility fallback drops the `model` axis from any dim that
-#: cannot take it, so TP dims that do divide still shard when batch cannot.
-DP_ACT_RULES: Dict[str, AxisName] = dict(
-    ACT_RULES, batch=("pod", "data", "model"),
-)
-
-
-def act_rules_for(cfg, mesh: Optional["Mesh"]) -> Dict[str, AxisName]:
-    """Pick TP (heads over model) or DP-heavy activation rules per arch."""
-    if mesh is None or "model" not in mesh.shape:
-        return ACT_RULES
-    nh = getattr(cfg, "num_heads", 0)
-    if nh and nh % mesh.shape["model"] != 0:
-        return DP_ACT_RULES
-    return ACT_RULES
-
-
-def rules_without_fsdp(rules: Dict[str, AxisName]) -> Dict[str, AxisName]:
-    out = dict(rules)
-    out["embed"] = None
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -176,16 +111,3 @@ def logical(x: jax.Array, names: Sequence[Optional[str]],
         return x
     spec = build_spec(x.shape, names, mesh, rules or current_act_rules())
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-
-
-def spec_tree(shapes, names_tree, rules=None, mesh=None):
-    """Map a pytree of (shape, names) -> pytree of PartitionSpec."""
-    mesh = mesh or current_mesh()
-    rules = rules or PARAM_RULES
-
-    def one(leaf):
-        shape, names = leaf
-        return build_spec(shape, names, mesh, rules)
-
-    return jax.tree.map(one, shapes, is_leaf=lambda l: isinstance(l, tuple)
-                        and len(l) == 2 and isinstance(l[0], tuple))

@@ -1,8 +1,4 @@
-"""Production mesh definitions.
-
-Single pod: (data=16, model=16) = 256 chips (TPU v5e pod slice).
-Multi-pod:  (pod=2, data=16, model=16) = 512 chips; the `pod` axis is pure
-data parallelism over the (slower) inter-pod links.
+"""Device meshes.
 
 Every mesh in the repo is built by ``make_mesh`` with *Auto* axes: the
 sharding helpers (``with_sharding_constraint`` under ``use_mesh``) and the
@@ -29,8 +25,3 @@ def make_mesh(shape, axes, devices=None):
                          axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
                          devices=devices)
 
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)

@@ -193,7 +193,7 @@ class TestSharding:
         assert batch.wire.devices() == {jax.devices()[0]}
 
     def test_event_axis_rule_registered(self):
-        from repro.parallel.sharding import ACT_RULES, build_spec
+        from repro.core.sharding import ACT_RULES, build_spec
         assert "events" in ACT_RULES
         mesh = make_mesh((1,), ("data",))
         spec = build_spec((4, 8), ("events", None), mesh, ACT_RULES)
@@ -202,7 +202,7 @@ class TestSharding:
     def test_simulate_under_mesh(self):
         """The batched engine runs (and matches) under an active 1-device
         mesh — the sharding constraints are exercised, not just no-ops."""
-        from repro.parallel.sharding import use_mesh
+        from repro.core.sharding import use_mesh
         batch = pack_events(_events([6, 6]))
         keys = event_keys(jax.random.key(0), range(2))
         resp = make_response(CFG)

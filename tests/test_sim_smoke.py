@@ -1,7 +1,7 @@
 """Fixed-seed smoke variants of the hypothesis-gated physics tests.
 
-``test_core_sim``, ``test_kernels``, ``test_fused_kernel`` and
-``test_models`` guard their property sweeps with a module-level
+``test_core_sim``, ``test_kernels`` and ``test_fused_kernel`` guard
+their property sweeps with a module-level
 ``pytest.importorskip("hypothesis")`` — which skips the WHOLE module,
 including their plain statistical tests, on boxes without hypothesis
 installed. These fixed-seed variants keep the load-bearing invariants
